@@ -50,6 +50,9 @@ def main(argv=None):
                          "artifacts (default: repo root; '' disables)")
     args = ap.parse_args(argv)
 
+    from repro.runtime.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import bench_construction as bc
     from . import bench_paper as bp
     from . import bench_engine as be

@@ -9,14 +9,14 @@ packed PECB arrays:
    vectorized lower-bound binary search over the per-vertex version CSR.
 2. **Link resolution** — the paper's per-node binary search (Alg 1 line 10)
    becomes a ``(B, N)`` vectorized lower-bound over the per-node entry CSR:
-   for every query b and forest node x we resolve (left, right, parent) at
-   ``ts_b`` in ``O(log t̄)`` steps, all queries and nodes in parallel.
-3. **Traversal** — BFS becomes masked min-label propagation with pointer
-   jumping over the (≤3-regular!) forest links: per round each active node
-   takes the min label over itself and its valid neighbours, then compresses
-   ``label ← label[label]``. The binary bound on children is exactly what
-   keeps each round at three gathers. Converges in O(log N) rounds for
-   balanced forests (worst case O(depth)); the fixpoint is detected by a
+   for every query b and forest node x we resolve x's parent at ``ts_b`` in
+   ``O(log t̄)`` steps, all queries and nodes in parallel.
+3. **Traversal** — BFS becomes pointer jumping. The ts-forest's parent and
+   child links agree, so the active nodes of one component form one subtree
+   of it, and every node of that subtree reaches the subtree's top node by
+   following active parent links. Each node starts at its active parent
+   (or itself) and jumps ``top <- top[top]`` until nothing changes: one
+   gather per round, O(log depth) rounds, the fixpoint detected by a
    ``lax.while_loop``.
 
 Node activity masking uses the forest-membership lifetimes recorded by the
@@ -28,8 +28,8 @@ them; the data-parallel propagation must mask them explicitly).
 Query API v2 additions (DESIGN.md §8):
 
 * :func:`batch_query_full` — besides the vertex mask, derives **edge
-  membership** on device: the converged labels give forest-node membership
-  (``label[b, x] == label[b, entry_b]``, the masked gather inside
+  membership** on device: the converged top nodes give forest-node
+  membership (``top[b, x] == top[b, entry_b]``, the masked gather inside
   :func:`_component_masks` that already produces the vertex mask), and a
   *core-time version* j is then a member iff its record covers ``ts_b``,
   ``ct_j <= te_b`` and the vertex mask is set at its ``src`` endpoint (one
@@ -201,8 +201,8 @@ def _host_layout_stratified(sx: StratifiedPECB):
     node ids shift by ``knode_ptr[ki]``, the per-stratum CSRs re-base onto
     the concatenated entry arrays, and per-vertex lookup becomes a lookup
     on the *slot* ``ki * n + u`` (``vrow_ptr`` has ``|K|*n+1`` rows). The
-    strata stay link-disjoint, so :func:`batch_query`'s min-label
-    propagation serves a mixed-k batch unchanged — per-query k enters only
+    strata stay link-disjoint, so :func:`batch_query`'s pointer jumping
+    serves a mixed-k batch unchanged — per-query k enters only
     as the host-computed entry slot, plus the ``ver_k == kq`` filter of
     :func:`batch_query_full_mixed` (the version arrays are the one place
     where records of different strata share an index space).
@@ -444,25 +444,22 @@ def _entry_nodes(dix: DeviceIndex, vlo, vhi, ts, te):
 
 
 def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te) -> jnp.ndarray:
-    """Steps 2-5: per-(query, node) link resolution, activity masking,
-    min-label propagation, membership collection.
+    """Steps 2-5: per-(query, node) parent resolution, activity masking,
+    pointer jumping to each component's top node, membership collection.
 
-    Returns the ``bool[B, n]`` vertex mask: forest-node membership is the
-    converged-label derivation (``label[x] == label[entry_b]``, masked by
-    activity), scattered to the member nodes' endpoints."""
+    Returns the ``bool[B, n]`` vertex mask: forest-node membership is
+    ``top[x] == top[entry_b]`` (masked by activity), scattered to the
+    member nodes' endpoints."""
     B = ts.shape[0]
     N = dix.num_nodes
     n = dix.n
     _, nsteps = _entry_steps(dix)
 
-    # -- 2. per-(query, node) link resolution ---------------------------
+    # -- 2. per-(query, node) parent link at ts --------------------------
     lo = jnp.broadcast_to(dix.row_ptr[:-1][None, :], (B, N))
     hi = jnp.broadcast_to(dix.row_ptr[1:][None, :], (B, N))
     idx = _lower_bound(dix.ent_ts, lo, hi, ts[:, None], nsteps)
-    idx_c = jnp.clip(idx, 0, dix.ent_ts.shape[0] - 1)
-    link_l = dix.ent_left[idx_c]
-    link_r = dix.ent_right[idx_c]
-    link_p = dix.ent_parent[idx_c]
+    parent = dix.ent_parent[jnp.clip(idx, 0, dix.ent_ts.shape[0] - 1)]
 
     # -- 3. per-(query, node) activity ----------------------------------
     active = (
@@ -471,34 +468,21 @@ def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te) -> jnp.ndarray:
         & (dix.node_ct[None, :] <= te[:, None])
     )
 
-    def neighbor_labels(labels, link):
-        ok = (link >= 0) & active
-        linkc = jnp.clip(link, 0, N - 1)
-        nb = jnp.take_along_axis(labels, linkc, axis=1)
-        nb_active = jnp.take_along_axis(active, linkc, axis=1)
-        return jnp.where(ok & nb_active, nb, N)
-
-    # -- 4. min-label propagation with pointer jumping -------------------
-    labels0 = jnp.where(active, jnp.arange(N, dtype=jnp.int32)[None, :], jnp.int32(N))
+    # -- 4. pointer jumping along active parent links --------------------
+    pc = jnp.clip(parent, 0, N - 1)
+    up = (parent >= 0) & active & jnp.take_along_axis(active, pc, axis=1)
+    top0 = jnp.where(up, pc, jnp.arange(N, dtype=jnp.int32)[None, :])
 
     def body(state):
-        labels, _ = state
-        cand = jnp.minimum(
-            jnp.minimum(neighbor_labels(labels, link_l), neighbor_labels(labels, link_r)),
-            neighbor_labels(labels, link_p),
-        )
-        new = jnp.minimum(labels, cand)
-        # pointer jumping: label <- label[label] (min is monotone-safe)
-        jc = jnp.clip(new, 0, N - 1)
-        jumped = jnp.where(new < N, jnp.take_along_axis(new, jc, axis=1), new)
-        new = jnp.minimum(new, jumped)
-        return new, jnp.any(new != labels)
+        top, _ = state
+        nxt = jnp.take_along_axis(top, top, axis=1)
+        return nxt, jnp.any(nxt != top)
 
-    labels, _ = jax.lax.while_loop(lambda s: s[1], body, (labels0, jnp.array(True)))
+    top, _ = jax.lax.while_loop(lambda s: s[1], body, (top0, jnp.array(True)))
 
-    # -- 5. membership: label[x] == label[entry_b], masked by activity ----
-    root = jnp.take_along_axis(labels, e0c[:, None], axis=1)
-    member = active & (labels == root) & e0_ok[:, None]
+    # -- 5. membership: top[x] == top[entry_b], masked by activity -------
+    root = jnp.take_along_axis(top, e0c[:, None], axis=1)
+    member = active & (top == root) & e0_ok[:, None]
 
     out = jnp.zeros((B, n), jnp.int32)
     rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, N))

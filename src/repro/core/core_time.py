@@ -51,9 +51,15 @@ vectorized run-length `_compress`.
 from __future__ import annotations
 
 import dataclasses
+import functools
 
 import numpy as np
 
+import jax
+import jax.numpy as jnp
+
+from ..kernels.segmented_select import (count_le_csr, kth_smallest_csr,
+                                        segmented_count_le)
 from .temporal_graph import TemporalGraph
 
 
@@ -343,21 +349,44 @@ def _sweep_host(g: TemporalGraph, k: int) -> np.ndarray:
 # JAX engine: jitted multi-start-time sweep (device plane)
 # ----------------------------------------------------------------------
 
+def _count_le_pallas(w, thr, seg, vptr):
+    """``count_le_csr``'s signature over the Pallas tile counter."""
+    return segmented_count_le(w, seg, thr, thr.shape[0])
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1, 2, 3))
+def _sweep_block(count_fn, k, inf, ksteps, tuv_rows, seg, dst, vptr, c0):
+    """One launch of the jitted sweep: ``lax.scan`` over the start times
+    whose ``t_uv`` rows are ``tuv_rows``, from the warm carry ``c0``.
+    Returns ``(carry, int32[rows, n] core times)``. Module-level, so every
+    stratum and every build with the same static ``(count_fn, k, inf,
+    ksteps)`` and operand shapes reuses one compiled program."""
+    def per_ts(c, tuv):
+        def body(state):
+            c, _ = state
+            w = jnp.maximum(tuv, c[dst])
+            cnt = count_fn(w, c, seg, vptr)
+            need = ~jnp.all((cnt >= k) | (c >= inf))
+            c = jax.lax.cond(
+                need,
+                lambda c: kth_smallest_csr(w, c, k, inf, ksteps, seg,
+                                           vptr, count_fn=count_fn),
+                lambda c: c, c)
+            return c, need
+
+        c, _ = jax.lax.while_loop(lambda s: s[1], body,
+                                  (c, jnp.array(True)))
+        return c, c
+
+    return jax.lax.scan(per_ts, c0, tuv_rows)
+
+
 def _sweep_jax(g: TemporalGraph, k: int, *, block: int = 512,
                use_pallas: bool = False) -> np.ndarray:
-    """Same least fixpoint as `_sweep_host`, as a jitted `lax.scan` over a
-    block of start times per launch (warm carry across launches). Each ts
-    runs verification + a `lax.cond`-gated counting-bisection climb, so
-    already-converged start times cost one segmented count."""
-    import functools
-
-    import jax
-    import jax.numpy as jnp
-
-    from repro.kernels.segmented_select import (count_le_csr,
-                                                kth_smallest_csr,
-                                                segmented_count_le)
-
+    """Same least fixpoint as `_sweep_host`, as the jitted `_sweep_block`
+    over a block of start times per launch (warm carry across launches).
+    Each ts runs verification + a `lax.cond`-gated counting-bisection
+    climb, so already-converged start times cost one segmented count."""
     n, t_max = g.n, g.t_max
     inf = t_max + 1
     vct = np.full((t_max + 1, n), inf, np.int32)
@@ -365,37 +394,7 @@ def _sweep_jax(g: TemporalGraph, k: int, *, block: int = 512,
         return vct
     csr = _pair_csr(g)
     ksteps = int(np.ceil(np.log2(inf + 1))) + 1
-
-    if use_pallas:
-        # interpret only where no real Pallas backend exists (CPU testing)
-        interpret = jax.default_backend() == "cpu"
-
-        def count_fn(w, thr, seg, vptr):
-            return segmented_count_le(w, seg, thr, n, interpret=interpret)
-    else:
-        count_fn = count_le_csr
-
-    @functools.partial(jax.jit, static_argnums=(0, 1, 2))
-    def sweep(k, inf, ksteps, tuv_rows, seg, dst, vptr, c0):
-        def per_ts(c, tuv):
-            def body(state):
-                c, _ = state
-                w = jnp.maximum(tuv, c[dst])
-                cnt = count_fn(w, c, seg, vptr)
-                need = ~jnp.all((cnt >= k) | (c >= inf))
-                c = jax.lax.cond(
-                    need,
-                    lambda c: kth_smallest_csr(w, c, k, inf, ksteps, seg,
-                                               vptr, count_fn=count_fn),
-                    lambda c: c, c)
-                return c, need
-
-            c, _ = jax.lax.while_loop(lambda s: s[1], body,
-                                      (c, jnp.array(True)))
-            return c, c
-
-        return jax.lax.scan(per_ts, c0, tuv_rows)
-
+    count_fn = _count_le_pallas if use_pallas else count_le_csr
     seg = jnp.asarray(csr.src.astype(np.int32))
     dst = jnp.asarray(csr.dst.astype(np.int32))
     vptr = jnp.asarray(csr.vptr.astype(np.int32))
@@ -403,7 +402,8 @@ def _sweep_jax(g: TemporalGraph, k: int, *, block: int = 512,
     for ts0 in range(1, t_max + 1, block):
         hi = min(ts0 + block, t_max + 1)
         rows = jnp.asarray(_tuv_rows(csr, ts0, hi, t_max))
-        c, out = sweep(k, inf, ksteps, rows, seg, dst, vptr, c)
+        c, out = _sweep_block(count_fn, k, inf, ksteps, rows, seg, dst,
+                              vptr, c)
         vct[ts0:hi] = np.asarray(out)
     return vct
 
@@ -471,6 +471,16 @@ def _edge_core_times_legacy(g: TemporalGraph, k: int) -> CoreTimeTable:
 ENGINES = ("auto", "host", "jax", "jax_pallas", "legacy")
 
 
+def resolve_engine(engine: str = "auto") -> str:
+    """The engine a core-time build runs for ``engine``: ``"auto"`` is the
+    jitted sweep on a non-CPU JAX backend and the host sweep on the CPU."""
+    if engine not in ENGINES:
+        raise ValueError(f"unknown engine {engine!r}, expected one of {ENGINES}")
+    if engine == "auto":
+        return "jax" if jax.default_backend() != "cpu" else "host"
+    return engine
+
+
 def edge_core_times(g: TemporalGraph, k: int, *,
                     engine: str = "auto") -> CoreTimeTable:
     """Compute CT(e)_ts for every edge and start time, delta-compressed.
@@ -482,15 +492,7 @@ def edge_core_times(g: TemporalGraph, k: int, *,
     counter as the selection inner op (compiled on device backends,
     interpreted on CPU). All engines return bit-identical tables.
     """
-    if engine not in ENGINES:
-        raise ValueError(f"unknown engine {engine!r}, expected one of {ENGINES}")
-    if engine == "auto":
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:  # pragma: no cover - jax is a hard dep in practice
-            backend = "cpu"
-        engine = "jax" if backend != "cpu" else "host"
+    engine = resolve_engine(engine)
     if engine == "legacy":
         return _edge_core_times_legacy(g, k)
     if engine == "host":
@@ -1007,15 +1009,7 @@ def stratified_core_times(g: TemporalGraph, ks=None, *,
     than the status quo) and exist for differential testing.
     """
     ks = _validate_ks(default_ks(g) if ks is None else ks)
-    if engine not in ENGINES:
-        raise ValueError(f"unknown engine {engine!r}, expected one of {ENGINES}")
-    if engine == "auto":
-        try:
-            import jax
-            backend = jax.default_backend()
-        except Exception:  # pragma: no cover - jax is a hard dep in practice
-            backend = "cpu"
-        engine = "jax" if backend != "cpu" else "host"
+    engine = resolve_engine(engine)
     if engine == "host":
         tables = [_compress(g, vct)
                   for vct in _sweep_host_stratified(g, ks)]

@@ -27,6 +27,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .contracts import ANY_FLOAT, ArraySpec, kernel_contract
 
 NEG_INF = -1e30
@@ -94,7 +95,7 @@ def _flash_vmem(a: dict) -> int:
     vmem_bound=_flash_vmem,
 )
 def flash_attention(q, k, v, *, causal: bool = False, bq: int = 128,
-                    bk: int = 128, interpret: bool = True):
+                    bk: int = 128, interpret: bool | None = None):
     """(B, S, H, dh) attention with KV (B, T, H, dh); H == kv-head count
     (expand GQA before calling). Returns (B, S, H, dh) in q.dtype."""
     B, S, H, dh = q.shape
@@ -137,7 +138,7 @@ def flash_attention(q, k, v, *, causal: bool = False, bq: int = 128,
             jax.ShapeDtypeStruct((B * H, Sp, 1), jnp.float32),
             jax.ShapeDtypeStruct((B * H, Sp, dh), jnp.float32),
         ],
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(qf, kf, vf)
     o = outs[0].reshape(B, H, Sp, dh).transpose(0, 2, 1, 3)
     return o[:, :S]

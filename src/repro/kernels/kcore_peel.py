@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .contracts import ANY_INT, ArraySpec, INT_OR_BOOL, kernel_contract
 
 DEFAULT_EDGE_BLOCK = 1024
@@ -62,7 +63,7 @@ def _degree_kernel(src_ref, dst_ref, alive_ref, out_ref):
 def degree_count(src, dst, alive, n: int, *,
                  edge_block: int = DEFAULT_EDGE_BLOCK,
                  vert_block: int = DEFAULT_VERT_BLOCK,
-                 interpret: bool = True) -> jnp.ndarray:
+                 interpret: bool | None = None) -> jnp.ndarray:
     """int32[n] alive-edge degrees. Pads edges/vertices to block multiples."""
     m = src.shape[0]
     mp = int(np.ceil(max(m, 1) / edge_block)) * edge_block
@@ -82,7 +83,7 @@ def degree_count(src, dst, alive, n: int, *,
         ],
         out_specs=pl.BlockSpec((vert_block,), lambda e, v: (v,)),
         out_shape=jax.ShapeDtypeStruct((np_,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(src_p, dst_p, alive_p)
     return out[:n]
 
@@ -116,7 +117,7 @@ def _peel_vmem(a: dict) -> int:
 )
 def peel_round(src, dst, alive, n: int, k: int, *,
                edge_block: int = DEFAULT_EDGE_BLOCK,
-               interpret: bool = True):
+               interpret: bool | None = None):
     """One fused peel round; returns the new alive mask (bool[m])."""
     deg = degree_count(src, dst, alive, n, interpret=interpret)
     m = src.shape[0]
@@ -137,6 +138,6 @@ def peel_round(src, dst, alive, n: int, k: int, *,
         ],
         out_specs=pl.BlockSpec((edge_block,), lambda e: (e,)),
         out_shape=jax.ShapeDtypeStruct((mp,), jnp.bool_),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(src_p, dst_p, alive_p, deg, jnp.array([k], jnp.int32))
     return out[:m]

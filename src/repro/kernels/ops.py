@@ -1,11 +1,12 @@
 """Public jit'd wrappers: Pallas kernel <-> pure-jnp reference dispatch.
 
-On this CPU container every kernel runs with ``interpret=True`` (the Pallas
-interpreter executes the kernel body op-for-op); on TPU the same
-``pl.pallas_call`` lowers to Mosaic. ``use_pallas(False)`` routes everything
-through the jnp references (the default inside big jitted training graphs,
-where XLA fusion is already the right tool and kernel dispatch would only
-fragment it).
+Each kernel's interpret mode follows the backend
+(:func:`repro.kernels.interpret_mode`): on the CPU the Pallas interpreter
+executes the kernel body op-for-op, on a TPU the same ``pl.pallas_call``
+lowers to Mosaic. ``use_pallas(False)`` routes everything through the jnp
+references (the default inside big jitted training graphs, where XLA
+fusion is already the right tool and kernel dispatch would only fragment
+it).
 """
 
 from __future__ import annotations
@@ -22,7 +23,6 @@ from . import ref
 from . import segment_matmul as _sm
 
 _USE_PALLAS = True
-_INTERPRET = True     # CPU container: interpret mode; flip on real TPUs
 
 
 def use_pallas(flag: bool):
@@ -32,13 +32,13 @@ def use_pallas(flag: bool):
 
 def degree_count(src, dst, alive, n: int):
     if _USE_PALLAS:
-        return _kp.degree_count(src, dst, alive, n, interpret=_INTERPRET)
+        return _kp.degree_count(src, dst, alive, n)
     return ref.degree_count(src, dst, alive, n)
 
 
 def kcore_peel_round(src, dst, alive, n: int, k: int):
     if _USE_PALLAS:
-        new_alive = _kp.peel_round(src, dst, alive, n, k, interpret=_INTERPRET)
+        new_alive = _kp.peel_round(src, dst, alive, n, k)
         return new_alive, jnp.any(new_alive != alive)
     return ref.kcore_peel_round(src, dst, alive, n, k)
 
@@ -50,20 +50,19 @@ def kcore_fixpoint(src, dst, n: int, k: int):
 
 def label_prop_round(labels, link_l, link_r, link_p, active):
     if _USE_PALLAS:
-        return _lp.label_prop_round(labels, link_l, link_r, link_p, active,
-                                    interpret=_INTERPRET)
+        return _lp.label_prop_round(labels, link_l, link_r, link_p, active)
     return ref.label_prop_round(labels, link_l, link_r, link_p, active)
 
 
 def matmul(a, b):
     if _USE_PALLAS:
-        return _sm.matmul(a, b, interpret=_INTERPRET)
+        return _sm.matmul(a, b)
     return ref.matmul(a, b)
 
 
 def segment_sum(vals, ids, num_segments: int):
     if _USE_PALLAS:
-        return _sm.segment_sum(vals, ids, num_segments, interpret=_INTERPRET)
+        return _sm.segment_sum(vals, ids, num_segments)
     return ref.segment_sum_sorted(vals, ids, num_segments)
 
 
@@ -73,5 +72,5 @@ def embedding_bag(table, ids, weights=None):
 
 def flash_attention(q, k, v, *, causal: bool = False):
     if _USE_PALLAS:
-        return _fa.flash_attention(q, k, v, causal=causal, interpret=_INTERPRET)
+        return _fa.flash_attention(q, k, v, causal=causal)
     return ref.flash_attention(q, k, v, causal=causal)

@@ -25,6 +25,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .contracts import ANY_FLOAT, ANY_INT, ArraySpec, kernel_contract
 
 
@@ -50,7 +51,7 @@ def _matmul_kernel(a_ref, b_ref, out_ref):
                               + v["bm"] * v["bn"]),
 )
 def matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
-           interpret: bool = True):
+           interpret: bool | None = None):
     """f32[M, N] = a @ b with (bm, bn, bk) VMEM tiles; pads to multiples."""
     M, K = a.shape
     K2, N = b.shape
@@ -69,7 +70,7 @@ def matmul(a, b, *, bm: int = 128, bn: int = 128, bk: int = 128,
         ],
         out_specs=pl.BlockSpec((bm, bn), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((Mp, Np), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(a_p, b_p)
     return out[:M, :N]
 
@@ -102,7 +103,7 @@ def _segsum_kernel(ids_ref, vals_ref, out_ref):
                               * a["vals"].shape[1]),
 )
 def segment_sum(vals, ids, num_segments: int, *, bm: int = 512, bs: int = 256,
-                interpret: bool = True):
+                interpret: bool | None = None):
     """f32[num_segments, d] scatter-add of rows by id, via one-hot GEMM."""
     m, d = vals.shape
     mp = int(np.ceil(max(m, 1) / bm)) * bm
@@ -118,12 +119,12 @@ def segment_sum(vals, ids, num_segments: int, *, bm: int = 512, bs: int = 256,
         ],
         out_specs=pl.BlockSpec((bs, d), lambda e, s: (s, 0)),
         out_shape=jax.ShapeDtypeStruct((sp, d), jnp.float32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(ids_p, vals_p)
     return out[:num_segments]
 
 
-def embedding_bag(table, ids, weights=None, *, interpret: bool = True):
+def embedding_bag(table, ids, weights=None):
     """(bags, k) -> (bags, d): gather + weighted within-bag sum.
 
     The gather stays an XLA gather (TPUs do this well); the bag reduction is

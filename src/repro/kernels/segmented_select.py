@@ -34,10 +34,13 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 
+from . import interpret_mode
 from .contracts import ANY_INT, ArraySpec, kernel_contract
 
+#: 1-D blocks match XLA's T(1024) tiling of long int32 vectors on a TPU;
+#: a 512 block is refused by Mosaic ("XLA layout ... does not match")
 DEFAULT_SLOT_BLOCK = 1024
-DEFAULT_SEG_BLOCK = 512
+DEFAULT_SEG_BLOCK = 1024
 
 
 # ----------------------------------------------------------------------
@@ -117,7 +120,7 @@ def _count_le_kernel(seg_ref, w_ref, thr_ref, out_ref):
 def segmented_count_le(w, seg, thr, n: int, *,
                        slot_block: int = DEFAULT_SLOT_BLOCK,
                        seg_block: int = DEFAULT_SEG_BLOCK,
-                       interpret: bool = True) -> jnp.ndarray:
+                       interpret: bool | None = None) -> jnp.ndarray:
     """int32[n] Pallas counterpart of :func:`count_le_csr` (``seg`` need not
     be sorted here — the histogram never assumes contiguity)."""
     e = w.shape[0]
@@ -136,7 +139,7 @@ def segmented_count_le(w, seg, thr, n: int, *,
         ],
         out_specs=pl.BlockSpec((seg_block,), lambda s, g: (g,)),
         out_shape=jax.ShapeDtypeStruct((npad,), jnp.int32),
-        interpret=interpret,
+        interpret=interpret_mode(interpret),
     )(seg_p, w_p, thr_p)
     return out[:n]
 
@@ -151,7 +154,7 @@ def segmented_count_le(w, seg, thr, n: int, *,
     # the inner segmented_count_le carries the per-step VMEM bound
 )
 def kth_smallest_pallas(w, seg, n: int, k: int, inf_value: int, *,
-                        lo=None, interpret: bool = True) -> jnp.ndarray:
+                        lo=None, interpret: bool | None = None) -> jnp.ndarray:
     """Per-segment clamped k-th smallest with the Pallas counter as the
     bisection inner op. Host-driven bisection loop (one kernel per step)."""
     lo = jnp.zeros(n, jnp.int32) if lo is None else lo.astype(jnp.int32)

@@ -21,6 +21,7 @@ import time
 from repro.core.kcore import k_max
 from repro.core.query_api import ResultMode, TCCSQuery
 from repro.core.temporal_graph import BENCH_WORKLOADS, bench_graph, random_queries
+from repro.runtime.compile_cache import enable_compile_cache
 from repro.serving import EngineConfig, ServingEngine
 
 
@@ -56,6 +57,7 @@ def main(argv=None):
 
     if args.batch < 1:
         ap.error("--batch must be >= 1")
+    enable_compile_cache()
     g = bench_graph(args.workload)
     k = args.k or max(2, int(0.7 * k_max(g)))
     cfg = EngineConfig(max_batch=args.batch, flush_ms=args.flush_ms,

@@ -25,18 +25,9 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 
 def shard_map(f, *, mesh: Mesh, in_specs, out_specs):
-    """Version-compatible shard_map with replication checking off.
-
-    ``jax.shard_map`` (with ``check_vma``) only exists on newer JAX; this
-    container's 0.4.x has ``jax.experimental.shard_map`` (with
-    ``check_rep``). Same semantics either way.
-    """
-    if hasattr(jax, "shard_map"):
-        return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
-                             out_specs=out_specs, check_vma=False)
-    from jax.experimental.shard_map import shard_map as _sm
-    return _sm(f, mesh=mesh, in_specs=in_specs, out_specs=out_specs,
-               check_rep=False)
+    """``jax.shard_map`` with replication (vma) checking off."""
+    return jax.shard_map(f, mesh=mesh, in_specs=in_specs,
+                         out_specs=out_specs, check_vma=False)
 
 
 def dp_axes(mesh: Mesh):

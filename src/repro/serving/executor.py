@@ -15,10 +15,11 @@ propagation shards over the batch dimension with ``jax.sharding`` — a 1-D
 ``('batch',)`` mesh, queries placed with ``PartitionSpec('batch')``, index
 arrays replicated by the partitioner (they are read-only gather operands).
 Buckets are sized to multiples of the device count so the placement is
-exact. Fallback: with one device (this container: CPU x1) or a bucket not
-divisible by the mesh, arrays stay uncommitted and jit runs single-device —
-semantics identical, tested by the sharded subprocess suite
-(tests/test_distributed.py).
+exact. With one device (one TPU chip, or the CPU the tests run on) or a
+bucket not divisible by the mesh, arrays stay uncommitted and jit runs
+single-device — semantics identical, tested on virtual CPU devices by the
+sharded subprocess suite (tests/test_distributed.py) and on four chips by
+``chip_smoke.py --chips 4``.
 """
 
 from __future__ import annotations

@@ -786,8 +786,9 @@ class IndexRegistry:
         (same epoch number *and* identical edge arrays — epoch counters
         reset across processes, so the arrays are authoritative) AND the
         strata the current policy asks for, upload to the device, and mint
-        a ``source="disk"`` handle. ``None`` on any miss or mismatch — the
-        caller falls through to the cold build."""
+        a ``source="disk"`` handle. ``None`` on a store miss, a failed
+        load or a mismatch — the caller falls through to the cold build.
+        A failed device upload raises."""
         workload = key
         span = self._span("index_promote", workload=workload, epoch=epoch)
         try:
@@ -814,11 +815,11 @@ class IndexRegistry:
         t0 = time.perf_counter()
         try:
             dev = to_device(stored.pecb)
-        except Exception as exc:
-            if self._metrics is not None:
-                self._metrics.count("store_load_failures")
+        except BaseException as exc:
+            # a device error is not a store miss: a cold build would hit
+            # the same device, so the failure surfaces on the build future
             span.set("error", repr(exc)).end()
-            return None
+            raise
         stages["device"] = total = time.perf_counter() - t0
         span.child("device", t0=t0).end()
         span.set("outcome", "promoted").end()

@@ -21,7 +21,7 @@ def run_with_devices(body: str, n: int = 8) -> str:
         os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n}"
         import numpy as np
         import jax, jax.numpy as jnp
-        from jax.sharding import NamedSharding, PartitionSpec as P
+        from jax.sharding import AxisType, NamedSharding, PartitionSpec as P
         assert jax.device_count() == {n}
     """) + textwrap.dedent(body)
     env = dict(os.environ)
@@ -37,7 +37,8 @@ def run_with_devices(body: str, n: int = 8) -> str:
 def test_vp_take_8way():
     run_with_devices("""
         from repro.runtime.sharding import make_vp_take
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         take = make_vp_take(mesh, leading=("data",))
         rng = np.random.default_rng(0)
         table = jnp.asarray(rng.normal(size=(64, 16)), jnp.float32)
@@ -55,7 +56,7 @@ def test_vp_take_8way():
 def test_compressed_grad_allreduce_8way():
     run_with_devices("""
         from repro.optim import compression
-        mesh = jax.make_mesh((8,), ("data",))
+        mesh = jax.make_mesh((8,), ("data",), axis_types=(AxisType.Auto,))
         fn = compression.make_compressed_grad_allreduce(mesh, axis="data")
         rng = np.random.default_rng(0)
         g = {"w": jnp.asarray(rng.normal(size=(32, 8)), jnp.float32)}
@@ -80,7 +81,8 @@ def test_smoke_train_step_sharded_8way():
         cfg = C.cell_model_cfg(spec, "train_4k", smoke=True)
         import dataclasses
         cfg = dataclasses.replace(cfg, n_head=4, n_kv=2, d_model=64)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         params = C.init_params(spec, cfg, jax.random.PRNGKey(0))
         p_specs = C.param_specs(spec, params, mesh)
         named = jax.tree.map(lambda s: NamedSharding(mesh, s), p_specs,
@@ -111,7 +113,7 @@ def test_batched_tccs_queries_shardable():
         u = jnp.asarray(rng.integers(0, g.n, B), jnp.int32)
         ts = jnp.asarray(rng.integers(1, g.t_max + 1, B), jnp.int32)
         te = jnp.minimum(ts + 5, g.t_max)
-        mesh = jax.make_mesh((8,), ("q",))
+        mesh = jax.make_mesh((8,), ("q",), axis_types=(AxisType.Auto,))
         sh = NamedSharding(mesh, P("q"))
         out = batch_query(dix, jax.device_put(u, sh), jax.device_put(ts, sh),
                           jax.device_put(te, sh))
@@ -132,7 +134,8 @@ def test_a2a_moe_matches_reference_dispatch():
     run_with_devices("""
         from repro.models import transformer as tfm
         from repro.runtime.moe_a2a import make_a2a_moe
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        mesh = jax.make_mesh((2, 4), ("data", "model"),
+                             axis_types=(AxisType.Auto,) * 2)
         mcfg = tfm.MoEConfig(n_experts=8, top_k=2, d_ff_expert=32,
                              capacity_factor=8.0)
         cfg = tfm.LMConfig("t", n_layer=1, d_model=64, n_head=2, n_kv=2,

@@ -441,3 +441,26 @@ class TestRegistryDiskTier:
         snap = metrics.snapshot(include_sources=False)["counters"]
         assert snap["store_load_failures"] == 1
         assert snap["store_commit_failures"] == 1
+
+    def test_device_upload_failure_in_promote_propagates(self, tmp_path,
+                                                         monkeypatch):
+        from repro.serving import registry as registry_mod
+        g = small_graph(seed=5)
+        reg = IndexRegistry(store=IndexStore(str(tmp_path)))
+        reg.register_graph("w", g)
+        reg.get("w")
+        reg.close()
+
+        def broken_upload(index):
+            raise RuntimeError("device lost")
+
+        monkeypatch.setattr(registry_mod, "to_device", broken_upload)
+        metrics = EngineMetrics()
+        reg = IndexRegistry(store=IndexStore(str(tmp_path)), metrics=metrics)
+        reg.register_graph("w", g)
+        with pytest.raises(RuntimeError, match="device lost"):
+            reg.get("w")
+        reg.close()
+        assert reg.builds == 0 and reg.promotions == 0
+        snap = metrics.snapshot(include_sources=False)["counters"]
+        assert "store_load_failures" not in snap
