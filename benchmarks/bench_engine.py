@@ -42,12 +42,12 @@ def bench_batch_query(name: str = "fb_like", batches=(32, 128, 512)):
 
     for B in batches:
         fn = jax.jit(batch_query)
-        out = fn(dix, u[:B], ts[:B], te[:B])
+        out, _ = fn(dix, u[:B], ts[:B], te[:B])
         out.block_until_ready()          # compile
         t0 = time.perf_counter()
         reps = 5
         for _ in range(reps):
-            out = fn(dix, u[:B], ts[:B], te[:B])
+            out, _ = fn(dix, u[:B], ts[:B], te[:B])
         out.block_until_ready()
         us_per_q = (time.perf_counter() - t0) / (reps * B) * 1e6
         rows.append([name, B, round(us_per_q, 2), round(seq_us, 2),
@@ -229,7 +229,7 @@ def bench_trace_overhead(name: str = "fb_like", n_q: int = 512,
     path), best-of-``reps`` per arm.
 
     Asserts on every run that >= 95% of completed queries carry the full
-    span chain (query -> queue -> route -> execute) and that the traced
+    span chain (query -> queue -> execute) and that the traced
     arm's Chrome trace export validates; on full runs additionally
     asserts traced p99 <= 1.05x untraced p99. Rows: one per arm,
     ``[workload, k, arm, queries, qps, p99_ms, chain_coverage, spans,
@@ -271,7 +271,7 @@ def bench_trace_overhead(name: str = "fb_like", n_q: int = 512,
                         by_trace[s.trace_id].add(s.name)
                     full = sum(
                         1 for r in results
-                        if {"query", "queue", "route", "execute"}
+                        if {"query", "queue", "execute"}
                         <= by_trace.get(r.provenance.trace_id, set()))
                     coverage = full / len(results)
                     spans = len(eng.tracer)

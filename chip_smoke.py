@@ -307,7 +307,7 @@ def four_chips(shape: dict, seed: int) -> None:
                         [s.te for s in specs[:BATCH]], BATCH)
         placed = [jax.device_put(jnp.asarray(a), ex.batch_sharding)
                   for a in q]
-        mask = batch_query(handle.device, *placed)
+        mask, _ = batch_query(handle.device, *placed)
         rows = sorted(sh.data.shape[0] for sh in placed[0].addressable_shards)
         in_devs = len(placed[0].sharding.device_set)
         out_devs = len(mask.sharding.device_set)

@@ -17,7 +17,8 @@ packed PECB arrays:
    following active parent links. Each node starts at its active parent
    (or itself) and jumps ``top <- top[top]`` until nothing changes: one
    gather per round, O(log depth) rounds, the fixpoint detected by a
-   ``lax.while_loop``.
+   ``lax.while_loop``. Every program returns the launch's round count as
+   one more ``int32`` scalar output, downloaded with the masks.
 
 Node activity masking uses the forest-membership lifetimes recorded by the
 builder: a node participates for query b iff
@@ -443,13 +444,15 @@ def _entry_nodes(dix: DeviceIndex, vlo, vhi, ts, te):
     return e0_ok, e0c
 
 
-def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te) -> jnp.ndarray:
+def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te):
     """Steps 2-5: per-(query, node) parent resolution, activity masking,
     pointer jumping to each component's top node, membership collection.
 
-    Returns the ``bool[B, n]`` vertex mask: forest-node membership is
-    ``top[x] == top[entry_b]`` (masked by activity), scattered to the
-    member nodes' endpoints."""
+    Returns ``(bool[B, n] vertex mask, int32 rounds)``: forest-node
+    membership is ``top[x] == top[entry_b]`` (masked by activity),
+    scattered to the member nodes' endpoints; ``rounds`` counts the
+    pointer-jump rounds the batch took, the last one (which finds no
+    change) included."""
     B = ts.shape[0]
     N = dix.num_nodes
     n = dix.n
@@ -474,11 +477,12 @@ def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te) -> jnp.ndarray:
     top0 = jnp.where(up, pc, jnp.arange(N, dtype=jnp.int32)[None, :])
 
     def body(state):
-        top, _ = state
+        top, _, rounds = state
         nxt = jnp.take_along_axis(top, top, axis=1)
-        return nxt, jnp.any(nxt != top)
+        return nxt, jnp.any(nxt != top), rounds + 1
 
-    top, _ = jax.lax.while_loop(lambda s: s[1], body, (top0, jnp.array(True)))
+    top, _, rounds = jax.lax.while_loop(
+        lambda s: s[1], body, (top0, jnp.array(True), jnp.int32(0)))
 
     # -- 5. membership: top[x] == top[entry_b], masked by activity -------
     root = jnp.take_along_axis(top, e0c[:, None], axis=1)
@@ -488,7 +492,7 @@ def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te) -> jnp.ndarray:
     rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, N))
     out = out.at[rows, jnp.broadcast_to(dix.node_u[None, :], (B, N))].max(member.astype(jnp.int32))
     out = out.at[rows, jnp.broadcast_to(dix.node_v[None, :], (B, N))].max(member.astype(jnp.int32))
-    return out.astype(bool)
+    return out.astype(bool), rounds
 
 
 def _version_member(dix: DeviceIndex, vertex_mask, ts, te):
@@ -506,11 +510,12 @@ def _version_member(dix: DeviceIndex, vertex_mask, ts, te):
 
 @jax.jit
 def batch_query(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
-                te: jnp.ndarray) -> jnp.ndarray:
-    """bool[B, n] vertex-membership of each query's k-core component."""
+                te: jnp.ndarray):
+    """(bool[B, n] vertex-membership of each query's k-core component,
+    int32 pointer-jump rounds of the launch)."""
     B = u.shape[0]
     if dix.num_nodes == 0:
-        return jnp.zeros((B, dix.n), bool)
+        return jnp.zeros((B, dix.n), bool), jnp.int32(0)
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
     return _component_masks(dix, e0_ok, e0c, ts, te)
 
@@ -518,7 +523,8 @@ def batch_query(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
 @jax.jit
 def batch_query_full(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
                      te: jnp.ndarray):
-    """(bool[B, n] vertex mask, bool[B, V] version-membership mask).
+    """(bool[B, n] vertex mask, bool[B, V] version-membership mask, int32
+    pointer-jump rounds).
 
     The version mask is the device-side EDGES/SUBGRAPH payload: exactly the
     member edges of each query's component (oracle-exact; see module doc).
@@ -526,10 +532,10 @@ def batch_query_full(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     B = u.shape[0]
     if dix.num_nodes == 0:
         return (jnp.zeros((B, dix.n), bool),
-                jnp.zeros((B, dix.ver_src.shape[0]), bool))
+                jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
-    vmask = _component_masks(dix, e0_ok, e0c, ts, te)
-    return vmask, _version_member(dix, vmask, ts, te)
+    vmask, rounds = _component_masks(dix, e0_ok, e0c, ts, te)
+    return vmask, _version_member(dix, vmask, ts, te), rounds
 
 
 @jax.jit
@@ -543,18 +549,19 @@ def batch_query_full_mixed(dix: DeviceIndex, slot: jnp.ndarray,
     host-side from the :class:`StratifiedPECB` handle; strata are
     link-disjoint so propagation needs no k mask) and ``kq`` the per-query
     k filtering the shared version arrays for the EDGES/SUBGRAPH payload.
-    Returns ``(bool[B, n] vertex mask, bool[B, V] version mask)``.
+    Returns ``(bool[B, n] vertex mask, bool[B, V] version mask, int32
+    pointer-jump rounds)``.
     """
     B = slot.shape[0]
     if dix.num_nodes == 0:
         return (jnp.zeros((B, dix.n), bool),
-                jnp.zeros((B, dix.ver_src.shape[0]), bool))
+                jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[slot],
                               dix.vrow_ptr[slot + 1], ts, te)
-    vmask = _component_masks(dix, e0_ok, e0c, ts, te)
+    vmask, rounds = _component_masks(dix, e0_ok, e0c, ts, te)
     vermask = (_version_member(dix, vmask, ts, te)
                & (dix.ver_k[None, :] == kq[:, None]))
-    return vmask, vermask
+    return vmask, vermask, rounds
 
 
 def mixed_slots(sx: StratifiedPECB,
@@ -578,7 +585,7 @@ def batch_query_mixed_np(sx: StratifiedPECB,
     ts = jnp.asarray([q[1] for q in queries], jnp.int32)
     te = jnp.asarray([q[2] for q in queries], jnp.int32)
     kq = jnp.asarray([q[3] for q in queries], jnp.int32)
-    vmask, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
+    vmask, _, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
     mask = np.asarray(vmask)
     return [set(np.nonzero(row)[0].tolist()) for row in mask]
 
@@ -594,7 +601,7 @@ def batch_query_mixed_edges_np(sx: StratifiedPECB,
     ts = jnp.asarray([q[1] for q in queries], jnp.int32)
     te = jnp.asarray([q[2] for q in queries], jnp.int32)
     kq = jnp.asarray([q[3] for q in queries], jnp.int32)
-    _, vermask = batch_query_full_mixed(dix, slot, ts, te, kq)
+    _, vermask, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
     vermask = np.asarray(vermask)[:, :dix.num_versions]
     eid = sx.strata.edge_id
     return [set(eid[np.nonzero(row)[0]].tolist()) for row in vermask]
@@ -603,7 +610,8 @@ def batch_query_mixed_edges_np(sx: StratifiedPECB,
 @jax.jit
 def window_sweep(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
                  te: jnp.ndarray) -> jnp.ndarray:
-    """bool[W, n] vertex masks for ONE vertex over W windows, one launch.
+    """(bool[W, n] vertex masks for ONE vertex over W windows, int32
+    pointer-jump rounds), one launch.
 
     ``u`` is a scalar: the vertex's entry segment ``[vrow_ptr[u],
     vrow_ptr[u+1])`` is resolved once and shared by every window — the
@@ -612,7 +620,7 @@ def window_sweep(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     """
     W = ts.shape[0]
     if dix.num_nodes == 0:
-        return jnp.zeros((W, dix.n), bool)
+        return jnp.zeros((W, dix.n), bool), jnp.int32(0)
     vlo = jnp.broadcast_to(dix.vrow_ptr[u], (W,))
     vhi = jnp.broadcast_to(dix.vrow_ptr[u + 1], (W,))
     e0_ok, e0c = _entry_nodes(dix, vlo, vhi, ts, te)
@@ -625,7 +633,7 @@ def batch_query_np(index: PECBIndex, queries: list[tuple[int, int, int]]) -> lis
     u = jnp.asarray([q[0] for q in queries], jnp.int32)
     ts = jnp.asarray([q[1] for q in queries], jnp.int32)
     te = jnp.asarray([q[2] for q in queries], jnp.int32)
-    mask = np.asarray(batch_query(dix, u, ts, te))
+    mask = np.asarray(batch_query(dix, u, ts, te)[0])
     return [set(np.nonzero(row)[0].tolist()) for row in mask]
 
 
@@ -640,6 +648,6 @@ def batch_query_edges_np(index: PECBIndex,
     u = jnp.asarray([q[0] for q in queries], jnp.int32)
     ts = jnp.asarray([q[1] for q in queries], jnp.int32)
     te = jnp.asarray([q[2] for q in queries], jnp.int32)
-    _, vermask = batch_query_full(dix, u, ts, te)
+    _, vermask, _ = batch_query_full(dix, u, ts, te)
     vermask = np.asarray(vermask)[:, :dix.num_versions]
     return [set(store.edge_id[np.nonzero(row)[0]].tolist()) for row in vermask]

@@ -50,8 +50,10 @@ vectorized run-length `_compress`.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
+import time
 
 import numpy as np
 
@@ -61,6 +63,42 @@ import jax.numpy as jnp
 from ..kernels.segmented_select import (count_le_csr, kth_smallest_csr,
                                         segmented_count_le)
 from .temporal_graph import TemporalGraph
+
+
+# ----------------------------------------------------------------------
+# Sub-stage timings of a stratified build
+# ----------------------------------------------------------------------
+
+#: the sub-stages of a core-time build, summed over strata
+SWEEP_STAGES = ("prepare", "dispatch", "sweep", "compress")
+
+
+class SweepStages:
+    """Seconds of each sub-stage of a core-time build, summed over strata,
+    in ``timings["core_times.<stage>"]``: ``prepare`` (pair CSR, t_uv
+    rows, operand uploads), ``dispatch`` (the ``_sweep_block`` call: a
+    compile or a persistent-cache load, then the enqueue; 0 on the host
+    engine), ``sweep`` (blocking on and downloading the jitted sweep's
+    result, or the numpy sweep itself) and ``compress``. Each stage run
+    is also a live child span of ``span`` (any object with the tracer's
+    ``child``), when one is given."""
+
+    def __init__(self, timings: dict | None = None, span=None):
+        self.timings = timings if timings is not None else {}
+        for name in SWEEP_STAGES:
+            self.timings.setdefault(f"core_times.{name}", 0.0)
+        self.span = span
+
+    @contextlib.contextmanager
+    def __call__(self, name: str):
+        key = f"core_times.{name}"
+        with (self.span.child(key) if self.span is not None
+              else contextlib.nullcontext()):
+            t0 = time.perf_counter()
+            try:
+                yield
+            finally:
+                self.timings[key] += time.perf_counter() - t0
 
 
 # ----------------------------------------------------------------------
@@ -382,29 +420,35 @@ def _sweep_block(count_fn, k, inf, ksteps, tuv_rows, seg, dst, vptr, c0):
 
 
 def _sweep_jax(g: TemporalGraph, k: int, *, block: int = 512,
-               use_pallas: bool = False) -> np.ndarray:
+               use_pallas: bool = False,
+               stages: SweepStages | None = None) -> np.ndarray:
     """Same least fixpoint as `_sweep_host`, as the jitted `_sweep_block`
     over a block of start times per launch (warm carry across launches).
     Each ts runs verification + a `lax.cond`-gated counting-bisection
     climb, so already-converged start times cost one segmented count."""
+    stage = stages if stages is not None else SweepStages()
     n, t_max = g.n, g.t_max
     inf = t_max + 1
     vct = np.full((t_max + 1, n), inf, np.int32)
     if g.m == 0 or t_max == 0:
         return vct
-    csr = _pair_csr(g)
-    ksteps = int(np.ceil(np.log2(inf + 1))) + 1
-    count_fn = _count_le_pallas if use_pallas else count_le_csr
-    seg = jnp.asarray(csr.src.astype(np.int32))
-    dst = jnp.asarray(csr.dst.astype(np.int32))
-    vptr = jnp.asarray(csr.vptr.astype(np.int32))
-    c = jnp.zeros(n, jnp.int32)
+    with stage("prepare"):
+        csr = _pair_csr(g)
+        ksteps = int(np.ceil(np.log2(inf + 1))) + 1
+        count_fn = _count_le_pallas if use_pallas else count_le_csr
+        seg = jnp.asarray(csr.src.astype(np.int32))
+        dst = jnp.asarray(csr.dst.astype(np.int32))
+        vptr = jnp.asarray(csr.vptr.astype(np.int32))
+        c = jnp.zeros(n, jnp.int32)
     for ts0 in range(1, t_max + 1, block):
         hi = min(ts0 + block, t_max + 1)
-        rows = jnp.asarray(_tuv_rows(csr, ts0, hi, t_max))
-        c, out = _sweep_block(count_fn, k, inf, ksteps, rows, seg, dst,
-                              vptr, c)
-        vct[ts0:hi] = np.asarray(out)
+        with stage("prepare"):
+            rows = jnp.asarray(_tuv_rows(csr, ts0, hi, t_max))
+        with stage("dispatch"):
+            c, out = _sweep_block(count_fn, k, inf, ksteps, rows, seg, dst,
+                                  vptr, c)
+        with stage("sweep"):
+            vct[ts0:hi] = np.asarray(out)
     return vct
 
 
@@ -941,7 +985,9 @@ def default_ks(g: TemporalGraph) -> tuple[int, ...]:
     return tuple(range(2, k_max(g) + 1))
 
 
-def _sweep_host_stratified(g: TemporalGraph, ks) -> list[np.ndarray]:
+def _sweep_host_stratified(g: TemporalGraph, ks,
+                           stages: SweepStages | None = None
+                           ) -> list[np.ndarray]:
     """Dense (t_max+1, n) vertex core times for every k in ``ks``, fused.
 
     One pair-CSR and one blocked t_uv table serve every stratum; inside a
@@ -953,12 +999,14 @@ def _sweep_host_stratified(g: TemporalGraph, ks) -> list[np.ndarray]:
     The inner loop is `_sweep_host`'s verbatim (one packed sort per
     iteration serves both the rank probe and the climb).
     """
+    stage = stages if stages is not None else SweepStages()
     n, t_max = g.n, g.t_max
     inf = t_max + 1
     vcts = [np.full((t_max + 1, n), inf, np.int32) for _ in ks]
     if g.m == 0 or t_max == 0 or not ks:
         return vcts
-    csr = _pair_csr(g)
+    with stage("prepare"):
+        csr = _pair_csr(g)
     deg = np.diff(csr.vptr)
     S = 1
     while S < inf + 2:
@@ -973,48 +1021,61 @@ def _sweep_host_stratified(g: TemporalGraph, ks) -> list[np.ndarray]:
     carry = [np.zeros(n, np.int32) for _ in ks]
     for ts0 in range(1, t_max + 1, TUV_BLOCK):
         ts1 = min(ts0 + TUV_BLOCK, t_max + 1)
-        tuv_rows = _tuv_rows(csr, ts0, ts1, t_max)
-        for ki, k in enumerate(ks):
-            c = carry[ki]
-            vct = vcts[ki]
-            seed_rows = vcts[ki - 1] if ki else None
-            for ts in range(ts0, ts1):
-                tuv = tuv_rows[ts - ts0]
-                if seed_rows is not None:
-                    np.maximum(c, seed_rows[ts], out=c)
-                while True:
-                    w = np.maximum(tuv, c[pd]).astype(kdtype, copy=False)
-                    key = base + w
-                    key.sort()
-                    cnt = np.searchsorted(key, vbase + c + 1) - vstart
-                    if bool(((cnt >= k) | (c >= inf)).all()):
-                        break
-                    c_new = np.full(n, inf, np.int32)
-                    c_new[has_k[ki]] = (key[sel[ki]] & (S - 1)) \
-                        if kdtype == np.int32 else key[sel[ki]] % S
-                    np.minimum(c_new, inf, out=c_new)
-                    np.maximum(c, c_new, out=c)
-                vct[ts] = c
+        with stage("prepare"):
+            tuv_rows = _tuv_rows(csr, ts0, ts1, t_max)
+        with stage("sweep"):
+            for ki, k in enumerate(ks):
+                c = carry[ki]
+                vct = vcts[ki]
+                seed_rows = vcts[ki - 1] if ki else None
+                for ts in range(ts0, ts1):
+                    tuv = tuv_rows[ts - ts0]
+                    if seed_rows is not None:
+                        np.maximum(c, seed_rows[ts], out=c)
+                    while True:
+                        w = np.maximum(tuv, c[pd]).astype(kdtype, copy=False)
+                        key = base + w
+                        key.sort()
+                        cnt = np.searchsorted(key, vbase + c + 1) - vstart
+                        if bool(((cnt >= k) | (c >= inf)).all()):
+                            break
+                        c_new = np.full(n, inf, np.int32)
+                        c_new[has_k[ki]] = (key[sel[ki]] & (S - 1)) \
+                            if kdtype == np.int32 else key[sel[ki]] % S
+                        np.minimum(c_new, inf, out=c_new)
+                        np.maximum(c, c_new, out=c)
+                    vct[ts] = c
     return vcts
 
 
 def stratified_core_times(g: TemporalGraph, ks=None, *,
-                          engine: str = "auto") -> StratifiedCoreTable:
+                          engine: str = "auto", timings: dict | None = None,
+                          span=None) -> StratifiedCoreTable:
     """One k-stratified core-time build covering every k in ``ks``
     (default: the full useful range ``default_ks(g)``).
 
     Every stratum is bit-identical to ``edge_core_times(g, k)`` — the
     host path runs the fused warm-seeded sweep `_sweep_host_stratified`;
-    other engines fall back to per-k sweeps (still sharing nothing worse
-    than the status quo) and exist for differential testing.
+    the jitted engines run per-k sweeps (`_sweep_jax`); ``legacy`` is the
+    differential-testing oracle. ``timings`` (a dict) receives the
+    :class:`SweepStages` sub-stage seconds, and each sub-stage run is a
+    live child span of ``span`` when one is given.
     """
     ks = _validate_ks(default_ks(g) if ks is None else ks)
     engine = resolve_engine(engine)
-    if engine == "host":
-        tables = [_compress(g, vct)
-                  for vct in _sweep_host_stratified(g, ks)]
-    else:
+    stage = SweepStages(timings, span)
+    if engine == "legacy":
         tables = [edge_core_times(g, k, engine=engine) for k in ks]
+        return StratifiedCoreTable.from_tables(g, ks, tables)
+    if engine == "host":
+        vcts = _sweep_host_stratified(g, ks, stage)
+    else:
+        vcts = (_sweep_jax(g, k, use_pallas=(engine == "jax_pallas"),
+                           stages=stage) for k in ks)
+    tables = []
+    for vct in vcts:
+        with stage("compress"):
+            tables.append(_compress(g, vct))
     return StratifiedCoreTable.from_tables(g, ks, tables)
 
 

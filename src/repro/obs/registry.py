@@ -25,6 +25,7 @@ from __future__ import annotations
 
 import math
 import random
+import time
 from typing import Callable
 
 from .locks import named_lock
@@ -112,6 +113,9 @@ class MetricsRegistry:
         self._gauges: dict[str, object] = {}          # value or callable
         self._hists: dict[str, LatencyHistogram] = {}
         self._sources: dict[str, Callable[[], dict]] = {}
+        #: perf_counter of construction or the last reset: a sampler of
+        #: intervals skips one that began before it (not in this window)
+        self.reset_at = time.perf_counter()
 
     # -- counters ---------------------------------------------------------
     def count(self, name: str, inc: int = 1) -> None:
@@ -187,6 +191,7 @@ class MetricsRegistry:
             self._counters.clear()
             self._gauges.clear()
             self._hists.clear()
+            self.reset_at = time.perf_counter()
 
     def format(self) -> str:
         snap = self.snapshot(include_sources=False)

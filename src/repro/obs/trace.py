@@ -25,6 +25,14 @@ are not resident anywhere except with their owner, so an abandoned span
 costs nothing. A disabled tracer hands out the :data:`NULL_SPAN`
 singleton, making every instrumentation site a few attribute lookups.
 
+A tracer may also write into a profiler's own trace: given an ``annotate``
+factory (the engine passes ``jax.profiler.TraceAnnotation``), a span
+entered as a context manager also enters ``annotate("repro." + name)`` on
+its thread and exits it when the span ends, so the span lands on the
+profiler's clock beside the device ops it caused. Backdated spans (a
+``t0`` in the past) and a disabled tracer annotate nothing. This module
+takes the factory as an argument and imports no profiler itself.
+
 The :class:`SlowQueryLog` hangs off the root-span finish path: a completed
 query whose duration crosses the threshold captures its full span tree
 (scanned from the ring buffer by trace id) plus the canonical query spec.
@@ -67,11 +75,11 @@ class Span:
 
     __slots__ = ("name", "cat", "trace_id", "span_id", "parent_id",
                  "t_start", "t_end", "tid", "thread_name", "attrs",
-                 "_tracer", "_ended")
+                 "_tracer", "_ended", "_live", "_ann")
 
     def __init__(self, tracer: "Tracer", name: str, cat: str,
                  trace_id: str, span_id: str, parent_id: str | None,
-                 t_start: float, attrs: dict):
+                 t_start: float, attrs: dict, live: bool = True):
         self._tracer = tracer
         self.name = name
         self.cat = cat
@@ -85,6 +93,8 @@ class Span:
         self.thread_name = t.name
         self.attrs = attrs
         self._ended = False
+        self._live = live        # started now, not backdated: annotatable
+        self._ann = None         # the open profiler annotation, if any
 
     # -- identity --------------------------------------------------------
     @property
@@ -120,10 +130,17 @@ class Span:
         if self.t_end < self.t_start:      # retrospective spans clamp
             self.t_end = self.t_start
         self._tracer._record(self)
+        if self._ann is not None:
+            ann, self._ann = self._ann, None
+            ann.__exit__(None, None, None)
 
     # -- context-manager use (thread-local current) ----------------------
     def __enter__(self) -> "Span":
         self._tracer._push(self)
+        annotate = self._tracer.annotate
+        if annotate is not None and self._live and not self._ended:
+            self._ann = annotate("repro." + self.name)
+            self._ann.__enter__()
         return self
 
     def __exit__(self, exc_type, exc, tb) -> None:
@@ -191,13 +208,16 @@ class Tracer:
     span and increments ``dropped`` — the export is a window, never a
     leak. ``enabled=False`` short-circuits every start to
     :data:`NULL_SPAN` (the off-switch costs one attribute check).
+    ``annotate`` is the optional profiler-annotation factory (module doc).
     """
 
-    def __init__(self, capacity: int = 16384, enabled: bool = True):
+    def __init__(self, capacity: int = 16384, enabled: bool = True,
+                 annotate=None):
         if capacity < 1:
             raise ValueError(f"tracer capacity must be >= 1, got {capacity}")
         self.capacity = capacity
         self.enabled = enabled
+        self.annotate = annotate
         self._lock = named_lock("tracer")
         self._spans: deque[Span] = deque()
         self.dropped = 0
@@ -235,20 +255,23 @@ class Tracer:
             return NULL_SPAN
         if parent is _IMPLICIT:
             parent = self.current()
+        live = t0 is None
+        if live:
+            t0 = time.perf_counter()
         if parent is None or parent is NULL_SPAN:
             span_id = _next_id()
-            return Span(self, name, cat, span_id, span_id, None,
-                        t0 if t0 is not None else time.perf_counter(), attrs)
+            return Span(self, name, cat, span_id, span_id, None, t0, attrs,
+                        live)
         if isinstance(parent, Span):
             parent = parent.ctx
         return Span(self, name, cat, parent.trace_id, _next_id(),
-                    parent.span_id,
-                    t0 if t0 is not None else time.perf_counter(), attrs)
+                    parent.span_id, t0, attrs, live)
 
     def span(self, name: str, **kw):
         """``with tracer.span("stage"): ...`` convenience — same arguments
         as :meth:`start_span`; the context manager pushes/pops the
-        thread-local current span and ends it on exit."""
+        thread-local current span, enters its profiler annotation, and
+        ends both on exit."""
         return self.start_span(name, **kw)
 
     def _record(self, span: Span) -> None:

@@ -25,6 +25,7 @@ from concurrent.futures import Future
 from typing import Callable, Sequence
 
 from repro.obs.locks import named_condition
+from repro.obs.trace import NULL_SPAN
 
 
 @dataclasses.dataclass
@@ -46,7 +47,7 @@ class Request:
     spec: object | None = None  # canonical TCCSQuery (query API v2)
     # open root query span (repro.obs.trace.Span) riding across the thread
     # boundary: the engine opens it on the caller thread, the planner hangs
-    # queue/route/execute children off it on the worker thread (explicit
+    # queue/execute children off it on the worker thread (explicit
     # context propagation, DESIGN.md §11.2). None for bare legacy requests.
     span: object | None = None
 
@@ -58,17 +59,20 @@ class MicroBatcher:
     return one result per request, in order. The batcher resolves futures
     and records queue-wait / end-to-end latency; a raising ``execute_fn``
     fails every future in the batch (no request is silently dropped).
+    Resolving a batch's futures is the live ``batcher.resolve`` span when
+    a ``tracer`` is given.
     """
 
     def __init__(self, execute_fn: Callable[[list[Request]], list],
                  *, max_batch: int = 256, flush_ms: float = 2.0,
-                 name: str = "batcher", metrics=None):
+                 name: str = "batcher", metrics=None, tracer=None):
         if max_batch < 1:
             raise ValueError(f"max_batch must be >= 1, got {max_batch}")
         self._execute = execute_fn
         self.max_batch = max_batch
         self.flush_s = flush_ms / 1e3
         self._metrics = metrics
+        self._tracer = tracer
         self._pending: deque[Request] = deque()
         self._cond = named_condition("batcher")
         self._stop = False
@@ -199,7 +203,10 @@ class MicroBatcher:
                     r.future.set_exception(e)
             return
         now = time.perf_counter()
-        for r, res in zip(batch, results):
-            r.future.set_result(res)
-            if self._metrics is not None:
-                self._metrics.observe("e2e", now - r.t_submit)
+        span = (self._tracer.span("batcher.resolve", batch=len(batch))
+                if self._tracer is not None else NULL_SPAN)
+        with span:
+            for r, res in zip(batch, results):
+                r.future.set_result(res)
+                if self._metrics is not None:
+                    self._metrics.observe("e2e", now - r.t_submit)

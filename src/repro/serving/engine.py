@@ -78,6 +78,8 @@ import warnings
 from concurrent.futures import Future
 from typing import Iterable, Sequence
 
+import jax
+
 from repro.core.query_api import (InvalidQueryError, Provenance, TCCSQuery,
                                   TCCSResult, WindowSweep, empty_result)
 from repro.obs.export import write_chrome_trace
@@ -158,7 +160,8 @@ class ServingEngine:
         self.metrics = EngineMetrics()
         # one tracer per engine (DESIGN.md §11.1): queries, background
         # builds/refreshes and compile events all record into this ring
-        self.tracer = Tracer(cfg.trace_buffer, enabled=cfg.trace)
+        self.tracer = Tracer(cfg.trace_buffer, enabled=cfg.trace,
+                             annotate=jax.profiler.TraceAnnotation)
         self.slow_queries = SlowQueryLog(cfg.slow_query_ms,
                                          tracer=self.tracer)
         self.cache = ResultCache(cfg.cache_capacity)
@@ -179,7 +182,7 @@ class ServingEngine:
         self.planner = QueryPlanner(
             self.executor, self.cache, self.metrics,
             host_threshold=cfg.host_threshold, min_bucket=cfg.min_bucket,
-            max_batch=cfg.max_batch)
+            max_batch=cfg.max_batch, tracer=self.tracer)
         # workload -> (handle the batcher's execute_fn is bound to, batcher)
         self._batchers: dict[str, tuple[IndexHandle, MicroBatcher]] = {}
         self._lock = named_lock("engine")
@@ -775,7 +778,7 @@ class ServingEngine:
                 self.planner.bind(handle),
                 max_batch=cfg.max_batch, flush_ms=cfg.flush_ms,
                 name=f"batcher-dispatch-{handle.key}",
-                metrics=self.metrics)
+                metrics=self.metrics, tracer=self.tracer)
             self._batchers[handle.key] = (handle, b)
         if stale is not None:
             stale.close()

@@ -24,6 +24,7 @@ sharded subprocess suite (tests/test_distributed.py) and on four chips by
 
 from __future__ import annotations
 
+import threading
 import time
 
 import numpy as np
@@ -35,6 +36,7 @@ from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 from repro.core.batch_query import (DeviceIndex, batch_query,
                                     batch_query_full,
                                     batch_query_full_mixed, window_sweep)
+from repro.obs.trace import Tracer
 
 #: Inert padding query: te < ts matches no core-time entry (cts are >= 1).
 PAD_QUERY = (0, 1, 0)
@@ -71,8 +73,15 @@ class ShardedExecutor:
     """Runs padded query batches on all visible devices.
 
     One executor per engine; stateless across calls apart from the device
-    mesh, so it is safe to share between batcher worker threads (jit
-    dispatch is thread-safe).
+    mesh and each calling thread's last launch times, so it is safe to
+    share between batcher worker threads (jit dispatch is thread-safe).
+
+    Every launch runs as three live spans on the calling thread:
+    ``executor.dispatch`` (pad, upload, enqueue, any compile),
+    ``executor.wait`` (until the outputs are ready; carries the launch's
+    ``jump_rounds``) and ``executor.download`` (the masks and the round
+    count in one ``device_get``). The rounds add to the ``jump_rounds``
+    counter and the launch to ``jump_launches``, traced or not.
     """
 
     def __init__(self, devices=None, *, metrics=None, tracer=None):
@@ -82,7 +91,8 @@ class ShardedExecutor:
         # jit caches are *recorded*, not inferred — a compile storm shows
         # up as jit_compile_* counters and "compile"-category trace spans
         self.metrics = metrics
-        self.tracer = tracer
+        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
+        self._local = threading.local()
         if self.num_devices > 1:
             self.mesh = Mesh(np.asarray(self.devices), ("batch",))
             self.batch_sharding = NamedSharding(self.mesh, P("batch"))
@@ -100,11 +110,10 @@ class ShardedExecutor:
             self.metrics.count("jit_compiles")
             self.metrics.count(f"jit_compile_{program}")
             self.metrics.observe("jit_compile", t1 - t0)
-        if self.tracer is not None:
-            self.tracer.start_span(
-                "jit_compile", parent=None, cat="compile", t0=t0,
-                program=program, bucket=bucket,
-                cache_size=fn._cache_size()).end(t1)
+        self.tracer.start_span(
+            "jit_compile", parent=None, cat="compile", t0=t0,
+            program=program, bucket=bucket,
+            cache_size=fn._cache_size()).end(t1)
 
     def _dispatch(self, fn, program: str, bucket: int, args):
         c0 = fn._cache_size()
@@ -113,6 +122,35 @@ class ShardedExecutor:
         if fn._cache_size() > c0:
             self._track_compile(fn, program, bucket, t0)
         return out
+
+    def _dispatch_span(self, program: str, bucket: int):
+        """The live ``executor.dispatch`` span of a launch; its start is
+        this thread's last dispatch time (:meth:`last_launch`)."""
+        self._local.dispatched = time.perf_counter()
+        return self.tracer.span("executor.dispatch", program=program,
+                                bucket=bucket)
+
+    def _collect(self, out) -> list:
+        """Wait for a launch's outputs (masks..., rounds), then download
+        them in one ``device_get``; returns the host masks and counts the
+        launch's pointer-jump rounds."""
+        with self.tracer.span("executor.wait") as wait:
+            # repro: ignore[hot-path-transfer] — block_until_ready, the sync
+            jax.block_until_ready(out)
+        self._local.waited = time.perf_counter()
+        with self.tracer.span("executor.download"):
+            # repro: ignore[hot-path-transfer] — device_get of masks + rounds
+            *masks, rounds = jax.device_get(out)
+        rounds = int(rounds)
+        wait.set("jump_rounds", rounds)
+        if self.metrics is not None:
+            self.metrics.count("jump_rounds", rounds)
+            self.metrics.count("jump_launches")
+        return [np.asarray(m) for m in masks]
+
+    def last_launch(self) -> tuple[float, float]:
+        """(dispatch start, wait end) of this thread's last launch."""
+        return self._local.dispatched, self._local.waited
 
     def align(self, bucket: int) -> int:
         """Round a bucket up to a multiple of the device count (no-op for
@@ -128,6 +166,11 @@ class ShardedExecutor:
         use this for padding metrics and pass the result to ``run``."""
         return self.align(bucket_size(b, min_bucket, max_batch))
 
+    def _check_aligned(self, bucket: int) -> None:
+        if self.align(bucket) != bucket:
+            raise ValueError(f"bucket {bucket} is not device-aligned; "
+                             "use final_bucket()")
+
     def _place(self, up, tsp, tep, bucket):
         if self.batch_sharding is not None and bucket % self.num_devices == 0:
             # the one deliberate upload: padded query arrays onto the
@@ -141,30 +184,28 @@ class ShardedExecutor:
         """bool[B, n] membership masks for the *unpadded* prefix. ``bucket``
         must come from ``final_bucket`` (already device-aligned)."""
         b = len(u)
-        if self.align(bucket) != bucket:
-            raise ValueError(f"bucket {bucket} is not device-aligned; "
-                             "use final_bucket()")
-        qu, qts, qte = self._place(*pad_queries(u, ts, te, bucket), bucket)
-        mask = self._dispatch(batch_query, "batch_query", bucket,
-                              (dix, qu, qts, qte))
-        # repro: ignore[hot-path-transfer] — the measured result download
-        return np.asarray(jax.device_get(mask))[:b]
+        self._check_aligned(bucket)
+        with self._dispatch_span("batch_query", bucket):
+            qu, qts, qte = self._place(*pad_queries(u, ts, te, bucket),
+                                       bucket)
+            out = self._dispatch(batch_query, "batch_query", bucket,
+                                 (dix, qu, qts, qte))
+        mask, = self._collect(out)
+        return mask[:b]
 
     def run_full(self, dix: DeviceIndex, u, ts, te,
                  bucket: int) -> tuple[np.ndarray, np.ndarray]:
         """(bool[B, n] vertex masks, bool[B, V] version-membership masks)
         for the unpadded prefix — the EDGES/SUBGRAPH-mode launch."""
         b = len(u)
-        if self.align(bucket) != bucket:
-            raise ValueError(f"bucket {bucket} is not device-aligned; "
-                             "use final_bucket()")
-        qu, qts, qte = self._place(*pad_queries(u, ts, te, bucket), bucket)
-        vmask, vermask = self._dispatch(batch_query_full, "batch_query_full",
-                                        bucket, (dix, qu, qts, qte))
-        # repro: ignore[hot-path-transfer] — measured result downloads
-        return (np.asarray(jax.device_get(vmask))[:b],
-                np.asarray(  # repro: ignore[hot-path-transfer] — ditto
-                    jax.device_get(vermask))[:b, :dix.num_versions])
+        self._check_aligned(bucket)
+        with self._dispatch_span("batch_query_full", bucket):
+            qu, qts, qte = self._place(*pad_queries(u, ts, te, bucket),
+                                       bucket)
+            out = self._dispatch(batch_query_full, "batch_query_full",
+                                 bucket, (dix, qu, qts, qte))
+        vmask, vermask = self._collect(out)
+        return vmask[:b], vermask[:b, :dix.num_versions]
 
     def run_full_mixed(self, dix: DeviceIndex, slot, ts, te, kq,
                        bucket: int) -> tuple[np.ndarray, np.ndarray]:
@@ -175,27 +216,26 @@ class ShardedExecutor:
         per bucket. Returns the same ``(vertex masks, version masks)``
         pair as :meth:`run_full`."""
         b = len(slot)
-        if self.align(bucket) != bucket:
-            raise ValueError(f"bucket {bucket} is not device-aligned; "
-                             "use final_bucket()")
-        qs, qts, qte = self._place(*pad_queries(slot, ts, te, bucket), bucket)
-        kq = np.asarray(kq, np.int32)
-        if kq.shape[0] < bucket:
-            # pad lanes are already inert via te < ts; kq=0 matches no
-            # stratum, keeping the version mask all-False twice over
-            kq = np.concatenate([kq, np.zeros(bucket - b, np.int32)])
-        if self.batch_sharding is not None and bucket % self.num_devices == 0:
-            # repro: ignore[hot-path-transfer] — padded operand upload
-            qkq = jax.device_put(jnp.asarray(kq), self.batch_sharding)
-        else:
-            qkq = jnp.asarray(kq)
-        vmask, vermask = self._dispatch(
-            batch_query_full_mixed, "batch_query_full_mixed", bucket,
-            (dix, qs, qts, qte, qkq))
-        # repro: ignore[hot-path-transfer] — measured result downloads
-        return (np.asarray(jax.device_get(vmask))[:b],
-                np.asarray(  # repro: ignore[hot-path-transfer] — ditto
-                    jax.device_get(vermask))[:b, :dix.num_versions])
+        self._check_aligned(bucket)
+        with self._dispatch_span("batch_query_full_mixed", bucket):
+            qs, qts, qte = self._place(*pad_queries(slot, ts, te, bucket),
+                                       bucket)
+            kq = np.asarray(kq, np.int32)
+            if kq.shape[0] < bucket:
+                # pad lanes are already inert via te < ts; kq=0 matches no
+                # stratum, keeping the version mask all-False twice over
+                kq = np.concatenate([kq, np.zeros(bucket - b, np.int32)])
+            if (self.batch_sharding is not None
+                    and bucket % self.num_devices == 0):
+                # repro: ignore[hot-path-transfer] — padded operand upload
+                qkq = jax.device_put(jnp.asarray(kq), self.batch_sharding)
+            else:
+                qkq = jnp.asarray(kq)
+            out = self._dispatch(
+                batch_query_full_mixed, "batch_query_full_mixed", bucket,
+                (dix, qs, qts, qte, qkq))
+        vmask, vermask = self._collect(out)
+        return vmask[:b], vermask[:b, :dix.num_versions]
 
     def run_sweep(self, dix: DeviceIndex, u: int, ts, te,
                   bucket: int) -> np.ndarray:
@@ -203,15 +243,15 @@ class ShardedExecutor:
         Windows pad with the inert (ts=1, te=0) window; the batch (window)
         dimension shards exactly like ``run``'s."""
         w = len(ts)
-        if self.align(bucket) != bucket:
-            raise ValueError(f"bucket {bucket} is not device-aligned; "
-                             "use final_bucket()")
-        _, tsp, tep = pad_queries([u] * w, ts, te, bucket)
-        _, qts, qte = self._place(np.zeros(bucket, np.int32), tsp, tep, bucket)
-        mask = self._dispatch(window_sweep, "window_sweep", bucket,
-                              (dix, jnp.int32(u), qts, qte))
-        # repro: ignore[hot-path-transfer] — the measured result download
-        return np.asarray(jax.device_get(mask))[:w]
+        self._check_aligned(bucket)
+        with self._dispatch_span("window_sweep", bucket):
+            _, tsp, tep = pad_queries([u] * w, ts, te, bucket)
+            _, qts, qte = self._place(np.zeros(bucket, np.int32), tsp, tep,
+                                      bucket)
+            out = self._dispatch(window_sweep, "window_sweep", bucket,
+                                 (dix, jnp.int32(u), qts, qte))
+        mask, = self._collect(out)
+        return mask[:w]
 
     @staticmethod
     def compile_count() -> int:

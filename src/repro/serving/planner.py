@@ -22,11 +22,19 @@ canonical spec it answered and :class:`Provenance` (route, index key,
 batch/bucket shape, stage timings). After execution the planner writes
 every (index key, canonical spec key) -> result into the LRU cache, so
 repeats are resolved on the submit path without ever reaching a batcher.
+
+On the device route the planner times mask-to-result assembly (the live
+``planner.assemble`` span and the ``device_assemble`` histogram) and each
+batcher worker's ``launch_gap``: from the end of the worker's previous
+launch's ``executor.wait`` to the start of this launch's
+``executor.dispatch``, the time the device had nothing of this engine's
+to run. A gap that began before the last metrics reset is not sampled.
 """
 
 from __future__ import annotations
 
 import dataclasses
+import threading
 import time
 
 import numpy as np
@@ -34,6 +42,7 @@ import numpy as np
 from repro.core.pecb_index import StratifiedPECB
 from repro.core.query_api import (Provenance, ResultMode, TCCSQuery,
                                   build_result)
+from repro.obs.trace import Tracer
 
 from .batcher import Request
 from .executor import ShardedExecutor
@@ -61,13 +70,16 @@ def assemble_device_results(store, specs, vmask, vermask,
 class QueryPlanner:
     def __init__(self, executor: ShardedExecutor, cache, metrics,
                  *, host_threshold: int = 8, min_bucket: int = 8,
-                 max_batch: int = 256):
+                 max_batch: int = 256, tracer=None):
         self.executor = executor
         self.cache = cache
         self.metrics = metrics
+        self.tracer = tracer if tracer is not None else Tracer(enabled=False)
         self.host_threshold = host_threshold
         self.min_bucket = min_bucket
         self.max_batch = max_batch
+        # per batcher worker: the end of its last launch's executor.wait
+        self._worker = threading.local()
 
     def route(self, handle, batch_size: int) -> str:
         if handle.pecb.num_nodes == 0:
@@ -88,8 +100,8 @@ class QueryPlanner:
     @staticmethod
     def _trace_pre_exec(batch: list[Request], route: str,
                         t_exec: float) -> None:
-        """Hang the retrospective ``queue`` span and the ``route`` decision
-        span off each request's root span (the engine attached it on the
+        """Hang the retrospective ``queue`` span off each request's root
+        span and stamp the route on it (the engine attached it on the
         caller thread; bare legacy requests carry none). The queue span is
         backdated to the batcher enqueue — by the time the worker runs a
         batch, the wait is already history."""
@@ -98,8 +110,16 @@ class QueryPlanner:
                 continue
             t_enq = r.t_enqueue or r.t_submit
             r.span.child("queue", t0=t_enq).end(t_exec)
-            r.span.child("route", t0=t_exec, route=route).end(t_exec)
             r.span.set("route", route)
+
+    def _observe_launch_gap(self) -> None:
+        """Sample this worker's ``launch_gap`` for the launch it just
+        made, and remember where the launch's wait ended."""
+        dispatched, waited = self.executor.last_launch()
+        prev = getattr(self._worker, "waited", None)
+        if prev is not None and prev >= self.metrics.reset_at:
+            self.metrics.observe("launch_gap", dispatched - prev)
+        self._worker.waited = waited
 
     def execute(self, handle, batch: list[Request]) -> list:
         b = len(batch)
@@ -174,6 +194,7 @@ class QueryPlanner:
                 vermask = None
             dt = time.perf_counter() - t0
             t_end = time.perf_counter()
+            self._observe_launch_gap()
             for es in exec_spans:
                 if es is not None:
                     es.end(t_end)
@@ -181,16 +202,20 @@ class QueryPlanner:
                               backend="pecb-device" + ("-full" if need_edges else ""),
                               index_key=handle.key, batch_size=b,
                               bucket=bucket, timings={"exec_s": dt})
-            results = assemble_device_results(store, specs, vmask, vermask,
-                                              prov)
-            # per-result provenance copies link each answer to its root
-            # query span (one launch, many traces)
-            results = [
-                dataclasses.replace(res, provenance=dataclasses.replace(
-                    res.provenance, trace_id=r.span.ids[0],
-                    span_id=r.span.ids[1]))
-                if r.span is not None else res
-                for r, res in zip(batch, results)]
+            with self.tracer.span("planner.assemble", batch=b):
+                t_asm = time.perf_counter()
+                results = assemble_device_results(store, specs, vmask,
+                                                  vermask, prov)
+                # per-result provenance copies link each answer to its
+                # root query span (one launch, many traces)
+                results = [
+                    dataclasses.replace(res, provenance=dataclasses.replace(
+                        res.provenance, trace_id=r.span.ids[0],
+                        span_id=r.span.ids[1]))
+                    if r.span is not None else res
+                    for r, res in zip(batch, results)]
+                self.metrics.observe("device_assemble",
+                                     time.perf_counter() - t_asm)
             self.metrics.observe("device_exec", dt)
             self.metrics.count("device_batches")
             self.metrics.count("device_queries", b)

@@ -37,7 +37,12 @@ Builds run on a small background pool and are exposed three ways:
 
 Each build records per-stage wall times (stratified core times, forests,
 device upload) on the handle and into the metrics sink
-(``index_build_<stage>``).
+(``index_build_<stage>``); a cold build also records the core-time
+sweep's sub-stages (``core_times.prepare`` / ``.dispatch`` / ``.sweep`` /
+``.compress``, see ``core_time.SweepStages``). Each stage is a live child
+span of the build's span, and the ``device`` stage waits until every
+``DeviceIndex`` array is on the device, so it times the upload and not
+only its enqueue.
 
 Graphs resolve by name: either registered explicitly (``register_graph``)
 or one of the named bench workloads (``BENCH_WORKLOADS``).
@@ -80,12 +85,14 @@ and rehome the survivors into the shifted timeline.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 import warnings
 from collections import OrderedDict
 from concurrent.futures import Future, ThreadPoolExecutor
 
+import jax
 import numpy as np
 
 from repro.obs.locks import named_lock
@@ -100,6 +107,19 @@ from repro.core.streaming import (extend_stratified_index,
                                   shrink_stratified_index)
 from repro.core.batch_query import (DeviceIndex, refresh_device,
                                     stratum_device, to_device)
+
+@contextlib.contextmanager
+def _stage(span, stages: dict, name: str, **attrs):
+    """One build stage: a live child span of ``span`` (yielded, so a
+    stage can parent its own sub-stages) and its seconds in
+    ``stages[name]``."""
+    with span.child(name, **attrs) as child:
+        t0 = time.perf_counter()
+        try:
+            yield child
+        finally:
+            stages[name] = time.perf_counter() - t0
+
 
 _K_KEY_DEPRECATION = (
     "per-k registry keys are deprecated: one k-stratified index serves "
@@ -417,18 +437,13 @@ class IndexRegistry:
                     f"handle {key!r} carries no stratified core-time table; "
                     "cannot refresh incrementally")
             ks = self._ks_for(key, g2)
-            t1 = time.perf_counter()
-            tab2 = extend_stratified_core_times(g2, old.tab, ks)
-            stages["core_times"] = time.perf_counter() - t1
-            span.child("core_times", t0=t1).end()
-            t1 = time.perf_counter()
-            idx2 = extend_stratified_index(g2, old.pecb, ks, strata=tab2)
-            stages["forest"] = time.perf_counter() - t1
-            span.child("forest", t0=t1).end()
-            t1 = time.perf_counter()
-            dev2, upload = refresh_device(old.pecb, old.device, idx2)
-            stages["device"] = time.perf_counter() - t1
-            span.child("device", t0=t1).end()
+            with _stage(span, stages, "core_times"):
+                tab2 = extend_stratified_core_times(g2, old.tab, ks)
+            with _stage(span, stages, "forest"):
+                idx2 = extend_stratified_index(g2, old.pecb, ks, strata=tab2)
+            with _stage(span, stages, "device"):
+                dev2, upload = refresh_device(old.pecb, old.device, idx2)
+                jax.block_until_ready(dev2)
             total = time.perf_counter() - t0
             handle = IndexHandle(key, g2, idx2, dev2, total, stages,
                                  epoch=epoch, tab=tab2)
@@ -564,32 +579,24 @@ class IndexRegistry:
             ks = tuple(k for k in self._ks_for(key, g2)
                        if k in cur.pecb.supported_ks)
             if cur.graph is g_old and cur.tab is not None:
-                t1 = time.perf_counter()
-                tab2 = shrink_stratified_core_times(g2, cur.tab, ks)
-                stages["core_times"] = time.perf_counter() - t1
-                span.child("core_times", t0=t1).end()
-                t1 = time.perf_counter()
-                idx2 = shrink_stratified_index(g2, cur.pecb, ks,
-                                               strata=tab2)
-                stages["forest"] = time.perf_counter() - t1
-                span.child("forest", t0=t1).end()
+                with _stage(span, stages, "core_times"):
+                    tab2 = shrink_stratified_core_times(g2, cur.tab, ks)
+                with _stage(span, stages, "forest"):
+                    idx2 = shrink_stratified_index(g2, cur.pecb, ks,
+                                                   strata=tab2)
             else:
                 # resident handle does not describe the pre-cut epoch (a
                 # cold-build race stored an intermediate snapshot): fall
                 # back to an exact cold build of the trimmed graph
                 ks = self._ks_for(key, g2)
-                t1 = time.perf_counter()
-                tab2 = stratified_core_times(g2, ks)
-                stages["core_times"] = time.perf_counter() - t1
-                span.child("core_times", t0=t1, cold=True).end()
-                t1 = time.perf_counter()
-                idx2 = build_stratified_index(g2, ks, strata=tab2)
-                stages["forest"] = time.perf_counter() - t1
-                span.child("forest", t0=t1, cold=True).end()
-            t1 = time.perf_counter()
-            dev2, upload = refresh_device(cur.pecb, cur.device, idx2)
-            stages["device"] = time.perf_counter() - t1
-            span.child("device", t0=t1).end()
+                with _stage(span, stages, "core_times", cold=True) as cs:
+                    tab2 = stratified_core_times(g2, ks, timings=stages,
+                                                 span=cs)
+                with _stage(span, stages, "forest", cold=True):
+                    idx2 = build_stratified_index(g2, ks, strata=tab2)
+            with _stage(span, stages, "device"):
+                dev2, upload = refresh_device(cur.pecb, cur.device, idx2)
+                jax.block_until_ready(dev2)
             total = time.perf_counter() - t0
             handle = IndexHandle(key, g2, idx2, dev2, total, stages,
                                  epoch=epoch, tab=tab2)
@@ -749,17 +756,12 @@ class IndexRegistry:
         stages = {}
         try:
             t0 = time.perf_counter()
-            tab = stratified_core_times(g, ks)
-            stages["core_times"] = time.perf_counter() - t0
-            span.child("core_times", t0=t0).end()
-            t1 = time.perf_counter()
-            idx = build_stratified_index(g, ks, strata=tab)
-            stages["forest"] = time.perf_counter() - t1
-            span.child("forest", t0=t1).end()
-            t1 = time.perf_counter()
-            dev = to_device(idx)
-            stages["device"] = time.perf_counter() - t1
-            span.child("device", t0=t1).end()
+            with _stage(span, stages, "core_times") as cs:
+                tab = stratified_core_times(g, ks, timings=stages, span=cs)
+            with _stage(span, stages, "forest"):
+                idx = build_stratified_index(g, ks, strata=tab)
+            with _stage(span, stages, "device"):
+                dev = jax.block_until_ready(to_device(idx))
             total = time.perf_counter() - t0
         except BaseException as exc:
             span.set("error", repr(exc)).end()
@@ -812,16 +814,15 @@ class IndexRegistry:
             span.set("outcome", "ks-mismatch").end()
             return None
         stages = {}
-        t0 = time.perf_counter()
         try:
-            dev = to_device(stored.pecb)
+            with _stage(span, stages, "device"):
+                dev = jax.block_until_ready(to_device(stored.pecb))
         except BaseException as exc:
             # a device error is not a store miss: a cold build would hit
             # the same device, so the failure surfaces on the build future
             span.set("error", repr(exc)).end()
             raise
-        stages["device"] = total = time.perf_counter() - t0
-        span.child("device", t0=t0).end()
+        total = stages["device"]
         span.set("outcome", "promoted").end()
         with self._lock:
             self.promotions += 1

@@ -697,7 +697,7 @@ class TestWitnessedDeviceQuery:
         dix = to_device(pecb)                 # layout checked on upload
         ts = jnp.asarray([1, 2], jnp.int32)
         te = jnp.asarray([5, 6], jnp.int32)
-        mask = np.asarray(window_sweep(dix, jnp.int32(0), ts, te))
+        mask = np.asarray(window_sweep(dix, jnp.int32(0), ts, te)[0])
         assert mask.shape == (2, g.n)
 
         deg = degree_count(g.src, g.dst, np.ones(g.m, bool), g.n)

@@ -115,8 +115,8 @@ def test_batched_tccs_queries_shardable():
         te = jnp.minimum(ts + 5, g.t_max)
         mesh = jax.make_mesh((8,), ("q",), axis_types=(AxisType.Auto,))
         sh = NamedSharding(mesh, P("q"))
-        out = batch_query(dix, jax.device_put(u, sh), jax.device_put(ts, sh),
-                          jax.device_put(te, sh))
+        out, _ = batch_query(dix, jax.device_put(u, sh),
+                             jax.device_put(ts, sh), jax.device_put(te, sh))
         # spot-check against the host index
         mask = np.asarray(out)
         for i in range(0, B, 7):

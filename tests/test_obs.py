@@ -173,6 +173,44 @@ class TestTracer:
         (s,) = tr.spans()
         assert "nope" in s.attrs["error"]
 
+    def test_annotate_only_live_with_spans(self):
+        """A ``with`` span enters ``annotate("repro." + name)`` and exits
+        it when it ends, nested in order; a backdated span, a span never
+        entered and a disabled tracer annotate nothing."""
+        events = []
+
+        class Fake:
+            def __init__(self, name):
+                self.name = name
+
+            def __enter__(self):
+                events.append(("enter", self.name))
+
+            def __exit__(self, *exc):
+                events.append(("exit", self.name))
+
+        tr = Tracer(annotate=Fake)
+        with tr.span("outer") as outer:
+            with outer.child("inner"):
+                events.append(("work", None))
+            with tr.span("queue", t0=time.perf_counter() - 1.0):
+                pass                                  # backdated
+            tr.start_span("open").end()               # never entered
+        assert events == [("enter", "repro.outer"), ("enter", "repro.inner"),
+                          ("work", None), ("exit", "repro.inner"),
+                          ("exit", "repro.outer")]
+        assert len(tr) == 4
+        with pytest.raises(ValueError):
+            with tr.span("boom"):
+                raise ValueError("nope")
+        assert events[-2:] == [("enter", "repro.boom"), ("exit", "repro.boom")]
+        events.clear()
+        off = Tracer(enabled=False, annotate=Fake)
+        with off.span("x") as s:
+            with s.child("y"):
+                pass
+        assert events == [] and len(off) == 0
+
 
 # ----------------------------------------------------------------------
 # Chrome trace export
@@ -319,11 +357,11 @@ class TestEngineTracing:
                          if s.span_id == prov.span_id]
                 assert len(roots) == 1 and roots[0].name == "query"
                 assert roots[0].attrs["route"] == "device"
-                assert {"query", "queue", "route", "execute"} <= \
-                    by_trace[prov.trace_id]
+                assert by_trace[prov.trace_id] == {"query", "queue",
+                                                   "execute"}
 
     def test_queue_span_crosses_batcher_thread(self):
-        """The root span starts on the caller thread; queue/route/execute
+        """The root span starts on the caller thread; queue/execute
         children are recorded from the batcher worker — same trace, two
         distinct thread ids (explicit ctx propagation, §11.2)."""
         g = _graph()

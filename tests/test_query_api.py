@@ -291,7 +291,7 @@ class TestWindowSweep:
         wins = [(d, min(d + 3, g.t_max)) for d in range(1, g.t_max + 1)]
         ts = jnp.asarray([w[0] for w in wins], jnp.int32)
         te = jnp.asarray([w[1] for w in wins], jnp.int32)
-        mask = np.asarray(window_sweep(dix, jnp.int32(u), ts, te))
+        mask = np.asarray(window_sweep(dix, jnp.int32(u), ts, te)[0])
         for (a, b), row in zip(wins, mask):
             assert set(np.nonzero(row)[0].tolist()) == \
                 pecb._component_vertices(u, a, b)

@@ -567,8 +567,13 @@ class TestAsyncRegistry:
         reg = IndexRegistry(metrics=metrics)
         reg.register_graph("g", gen_temporal_graph(n=14, m=70, t_max=6, seed=3))
         h = reg.get("g")
-        assert set(h.build_stages) == {"core_times", "forest", "device"}
+        subs = {f"core_times.{s}" for s in
+                ("prepare", "dispatch", "sweep", "compress")}
+        assert set(h.build_stages) == {"core_times", "forest",
+                                       "device"} | subs
         assert all(v >= 0 for v in h.build_stages.values())
+        assert sum(h.build_stages[s] for s in subs) <= \
+            h.build_stages["core_times"]
         snap = metrics.snapshot()
         for stage in ("core_times", "forest", "device"):
             assert snap["latency"][f"index_build_{stage}"]["count"] == 1

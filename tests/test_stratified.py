@@ -116,7 +116,7 @@ class TestMixedKDevice:
         slot = mixed_slots(sx, [(u, k) for (u, _, _), k in zip(qs, ks)])
         ts = np.asarray([q[1] for q in qs], np.int32)
         te = np.asarray([q[2] for q in qs], np.int32)
-        vmask = np.asarray(batch_query(dix, slot, ts, te))
+        vmask = np.asarray(batch_query(dix, slot, ts, te)[0])
         for i, ((u, a, b), k) in enumerate(zip(qs, ks)):
             want = sx.slice_k(k)._component_vertices(u, a, b)
             assert frozenset(np.nonzero(vmask[i])[0].tolist()) == \
@@ -134,7 +134,7 @@ class TestMixedKDevice:
         ts = np.asarray([q[1] for q in qs], np.int32)
         te = np.asarray([q[2] for q in qs], np.int32)
         kq = np.asarray(ks, np.int32)
-        _, vermask = batch_query_full_mixed(dix, slot, ts, te, kq)
+        _, vermask, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
         vermask = np.asarray(vermask)
         for i, ((u, a, b), k) in enumerate(zip(qs, ks)):
             got = {int(store.edge_id[j])
@@ -151,7 +151,7 @@ class TestMixedKDevice:
         u = 1
         for k in sx.supported_ks:
             slot = np.full(len(windows), sx.k_index(k) * g.n + u, np.int32)
-            vmask = np.asarray(window_sweep(dix, slot, ts, te))
+            vmask = np.asarray(window_sweep(dix, slot, ts, te)[0])
             for i, (a, b) in enumerate(windows):
                 want = frozenset(sx.slice_k(k)._component_vertices(u, a, b))
                 assert frozenset(np.nonzero(vmask[i])[0].tolist()) == want
@@ -179,9 +179,9 @@ class TestMixedKDevice:
                                       np.asarray(getattr(ref, f))), (k, f)
             assert sd.num_versions == ref.num_versions
             slot = np.full(len(windows), sx.k_index(k) * g.n + u, np.int32)
-            fused = np.asarray(window_sweep(dix, slot, ts, te))
+            fused = np.asarray(window_sweep(dix, slot, ts, te)[0])
             sliced = np.asarray(window_sweep(
-                sd, np.full(len(windows), u, np.int32), ts, te))
+                sd, np.full(len(windows), u, np.int32), ts, te)[0])
             assert np.array_equal(fused, sliced), k
         with pytest.raises(KeyError):
             stratum_device(dix, sx, 99)

@@ -204,8 +204,8 @@ class TestDeviceRefresh:
         u = jnp.asarray([q[0] for q in qs], jnp.int32)
         ts = jnp.asarray([q[1] for q in qs], jnp.int32)
         te = jnp.asarray([q[2] for q in qs], jnp.int32)
-        assert np.array_equal(np.asarray(batch_query(dix1, u, ts, te)),
-                              np.asarray(batch_query(fresh, u, ts, te)))
+        assert np.array_equal(np.asarray(batch_query(dix1, u, ts, te)[0]),
+                              np.asarray(batch_query(fresh, u, ts, te)[0]))
 
     def test_noop_refresh_reuses_everything(self):
         g = gen_temporal_graph(n=20, m=150, t_max=10, seed=22)

@@ -18,7 +18,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.core.batch_query import (DeviceIndex, _host_layout, batch_query,
-                                    batch_query_full_mixed, window_sweep)
+                                    batch_query_full, batch_query_full_mixed,
+                                    window_sweep)
 from repro.core.core_time import (_count_le_pallas, _pair_csr, _sweep_block,
                                   _tuv_rows, count_le_csr)
 from repro.core.pecb_index import build_stratified_index
@@ -74,13 +75,16 @@ def _total_bytes(compiled) -> int:
             - m.alias_size_in_bytes)
 
 
-@pytest.mark.parametrize("program", ["batch_query", "batch_query_full_mixed",
+@pytest.mark.parametrize("program", ["batch_query", "batch_query_full",
+                                     "batch_query_full_mixed",
                                      "window_sweep"])
 def test_query_program_fits_one_chip(one_chip, fb_like, program):
     g, sx = fb_like
     q = _struct((B,), one_chip)
     if program == "batch_query":
         lowered = batch_query.lower(_device_index(sx, one_chip), q, q, q)
+    elif program == "batch_query_full":
+        lowered = batch_query_full.lower(_device_index(sx, one_chip), q, q, q)
     elif program == "batch_query_full_mixed":
         lowered = batch_query_full_mixed.lower(_device_index(sx, one_chip),
                                                q, q, q, q)
@@ -89,6 +93,9 @@ def test_query_program_fits_one_chip(one_chip, fb_like, program):
         k = sx.supported_ks[len(sx.supported_ks) // 2]
         lowered = window_sweep.lower(_device_index(sx.slice_k(k), one_chip),
                                      _struct((), one_chip), q, q)
+    # the launch's outputs end with its int32 pointer-jump round count
+    rounds = jax.tree_util.tree_leaves(lowered.out_info)[-1]
+    assert rounds.shape == () and rounds.dtype == jnp.int32
     compiled = lowered.compile()
     assert 0 < _total_bytes(compiled) < HBM_BYTES
 
