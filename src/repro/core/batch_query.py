@@ -8,9 +8,9 @@ packed PECB arrays:
 1. **Entry points** — the paper's per-vertex lookup (Alg 1 line 3) becomes a
    vectorized lower-bound binary search over the per-vertex version CSR.
 2. **Link resolution** — the paper's per-node binary search (Alg 1 line 10)
-   becomes a ``(B, N)`` vectorized lower-bound over the per-node entry CSR:
-   for every query b and forest node x we resolve x's parent at ``ts_b`` in
-   ``O(log t̄)`` steps, all queries and nodes in parallel.
+   becomes a ``(B, W)`` vectorized lower-bound over the per-node entry CSR:
+   for every query b and forest node x of its window we resolve x's parent
+   at ``ts_b`` in ``O(log t̄)`` steps, all queries and nodes in parallel.
 3. **Traversal** — BFS becomes pointer jumping. The ts-forest's parent and
    child links agree, so the active nodes of one component form one subtree
    of it, and every node of that subtree reaches the subtree's top node by
@@ -19,6 +19,14 @@ packed PECB arrays:
    gather per round, O(log depth) rounds, the fixpoint detected by a
    ``lax.while_loop``. Every program returns the launch's round count as
    one more ``int32`` scalar output, downloaded with the masks.
+   Steps 2 and 3 run over each query's **stratum window**, not over every
+   node of the mirror: on the fused k-stratified mirror a query at k can
+   only reach stratum k's nodes (the strata are link-disjoint), so row b
+   reads the ``W = max_stratum_nodes`` contiguous nodes that start at its
+   stratum's first node ``knode_ptr[slot_b // n]``, masks the nodes of
+   other strata in that window as inactive, and pointer-jumps in
+   window-local ids. The work per row is ``(B, W)`` with W the widest
+   stratum; on a per-k mirror W is every node.
 
 Node activity masking uses the forest-membership lifetimes recorded by the
 builder: a node participates for query b iff
@@ -120,9 +128,12 @@ class DeviceIndex:
     ver_ct: jnp.ndarray
     ver_src: jnp.ndarray
     ver_k: jnp.ndarray        # per-version stratum k (constant per-k mirror)
+    # int32[|K|+1]: first node of each stratum ([0, N] on a per-k mirror)
+    knode_ptr: jnp.ndarray
     max_node_entries: int     # static: longest per-node entry list
     max_vert_entries: int     # static: longest per-vertex entry list
     num_versions: int         # static: true version count (pre-padding)
+    max_stratum_nodes: int    # static: widest stratum, the node window W
 
     @property
     def num_nodes(self) -> int:
@@ -133,10 +144,10 @@ _ARRAY_FIELDS = (
     "node_u", "node_v", "node_ct", "live_from", "live_to",
     "row_ptr", "ent_ts", "ent_left", "ent_right", "ent_parent",
     "vrow_ptr", "vent_ts", "vent_node",
-    "ver_ts_from", "ver_ts_to", "ver_ct", "ver_src", "ver_k",
+    "ver_ts_from", "ver_ts_to", "ver_ct", "ver_src", "ver_k", "knode_ptr",
 )
 _META_FIELDS = ("n", "t_max", "max_node_entries", "max_vert_entries",
-                "num_versions")
+                "num_versions", "max_stratum_nodes")
 
 jax.tree_util.register_pytree_node(
     DeviceIndex,
@@ -184,6 +195,7 @@ def _host_layout(index):
         "ver_src": i32(store.src) if has_vers else pad0,
         "ver_k": (np.full(store.num_versions, index.k, np.int32)
                   if has_vers else pad0),
+        "knode_ptr": i32([0, index.num_nodes]),
     }
     meta = {
         "n": index.n,
@@ -191,6 +203,7 @@ def _host_layout(index):
         "max_node_entries": int(seg.max()) if seg.size else 0,
         "max_vert_entries": int(vseg.max()) if vseg.size else 0,
         "num_versions": store.num_versions if has_vers else 0,
+        "max_stratum_nodes": index.num_nodes,
     }
     return meta, arrays
 
@@ -204,9 +217,12 @@ def _host_layout_stratified(sx: StratifiedPECB):
     on the *slot* ``ki * n + u`` (``vrow_ptr`` has ``|K|*n+1`` rows). The
     strata stay link-disjoint, so :func:`batch_query`'s pointer jumping
     serves a mixed-k batch unchanged — per-query k enters only
-    as the host-computed entry slot, plus the ``ver_k == kq`` filter of
-    :func:`batch_query_full_mixed` (the version arrays are the one place
-    where records of different strata share an index space).
+    as the host-computed entry slot, from which the device reads the
+    query's stratum (``knode_ptr[slot // n]``) and propagates over that
+    stratum's node window alone (:func:`_component_masks`), plus the
+    ``ver_k == kq`` filter of :func:`batch_query_full_mixed` (the version
+    arrays are the one place where records of different strata share an
+    index space).
     """
     i32 = _i32
     K = len(sx.ks)
@@ -266,13 +282,16 @@ def _host_layout_stratified(sx: StratifiedPECB):
         "ver_k": (np.repeat(np.asarray(sx.ks, np.int32),
                             np.diff(st.kptr)).astype(np.int32)
                   if V else pad0),
+        "knode_ptr": _i32(sx.knode_ptr, "stratum node pointer"),
     }
+    knodes = np.diff(sx.knode_ptr)
     meta = {
         "n": n,
         "t_max": sx.t_max,
         "max_node_entries": int(seg.max()) if seg.size else 0,
         "max_vert_entries": int(vseg.max()) if vseg.size else 0,
         "num_versions": V,
+        "max_stratum_nodes": int(knodes.max()) if knodes.size else 0,
     }
     return meta, arrays
 
@@ -346,10 +365,10 @@ def stratum_device(dix: DeviceIndex, sx: StratifiedPECB,
                    k: int) -> DeviceIndex:
     """Carve ONE stratum's block out of a fused stratified device mirror.
 
-    A single-k program (the window sweep) pays propagation cost on every
-    forest node of the mirror it runs against — on the fused mixed-k
-    mirror, every stratum's nodes, a |K|-fold tax for a launch that can
-    only ever touch one stratum. This slices the ``[knode_ptr[ki],
+    A single-k program (the window sweep) pays propagation cost on a
+    node window as wide as the mirror's widest stratum — on the fused
+    mixed-k mirror, the k = 2 stratum's width for a launch that can only
+    ever touch stratum k. This slices the ``[knode_ptr[ki],
     knode_ptr[ki+1])`` node block plus its entry / vertex-entry / version
     segments into a standalone per-k :class:`DeviceIndex` (a handful of
     eager device slices, no host round trip), with forest-node links
@@ -399,9 +418,11 @@ def stratum_device(dix: DeviceIndex, sx: StratifiedPECB,
         ver_ct=dix.ver_ct[slo:shi] if has_ver else pad0,
         ver_src=dix.ver_src[slo:shi] if has_ver else pad0,
         ver_k=dix.ver_k[slo:shi] if has_ver else pad0,
+        knode_ptr=jnp.asarray([0, nhi - nlo], jnp.int32),
         max_node_entries=dix.max_node_entries,
         max_vert_entries=dix.max_vert_entries,
         num_versions=shi - slo,
+        max_stratum_nodes=nhi - nlo,
     )
 
 
@@ -444,9 +465,19 @@ def _entry_nodes(dix: DeviceIndex, vlo, vhi, ts, te):
     return e0_ok, e0c
 
 
-def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te):
+def _component_masks(dix: DeviceIndex, slot, e0_ok, e0c, ts, te):
     """Steps 2-5: per-(query, node) parent resolution, activity masking,
     pointer jumping to each component's top node, membership collection.
+
+    Each query works on its own stratum's node window of static width
+    ``W = dix.max_stratum_nodes``: row b reads ``W`` contiguous nodes from
+    its stratum's first node ``knode_ptr[slot_b // n]`` (moved left where
+    that would run past the mirror's end, since a dynamic slice clamps its
+    start) and masks the nodes outside its stratum as inactive. The strata
+    are link-disjoint, so the entry node and every parent link a member
+    can follow lie in the window, and propagation runs in window-local
+    ids. On a mirror of one stratum ``W`` is every node and the window is
+    the whole mirror.
 
     Returns ``(bool[B, n] vertex mask, int32 rounds)``: forest-node
     membership is ``top[x] == top[entry_b]`` (masked by activity),
@@ -455,26 +486,43 @@ def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te):
     change) included."""
     B = ts.shape[0]
     N = dix.num_nodes
+    W = dix.max_stratum_nodes
     n = dix.n
     _, nsteps = _entry_steps(dix)
 
+    # -- each row's node window [start_b, start_b + W) -------------------
+    ki = slot // n
+    first = dix.knode_ptr[ki]
+    start = jnp.minimum(first, N - W)
+    lo = (first - start)[:, None]
+    hi = lo + (dix.knode_ptr[ki + 1] - first)[:, None]
+    local = jnp.arange(W, dtype=jnp.int32)[None, :]
+    inside = (lo <= local) & (local < hi)
+
+    def rows(a, w=W):
+        return jax.vmap(lambda s: jax.lax.dynamic_slice(a, (s,), (w,)))(start)
+
     # -- 2. per-(query, node) parent link at ts --------------------------
-    lo = jnp.broadcast_to(dix.row_ptr[:-1][None, :], (B, N))
-    hi = jnp.broadcast_to(dix.row_ptr[1:][None, :], (B, N))
-    idx = _lower_bound(dix.ent_ts, lo, hi, ts[:, None], nsteps)
-    parent = dix.ent_parent[jnp.clip(idx, 0, dix.ent_ts.shape[0] - 1)]
+    ptr = rows(dix.row_ptr, W + 1)
+    idx = _lower_bound(dix.ent_ts, ptr[:, :-1], ptr[:, 1:], ts[:, None],
+                       nsteps)
+    # window-local parent ids: a no-link (-1) stays negative, and the
+    # parent of a node inside the stratum lies inside it
+    parent = (dix.ent_parent[jnp.clip(idx, 0, dix.ent_ts.shape[0] - 1)]
+              - start[:, None])
 
     # -- 3. per-(query, node) activity ----------------------------------
     active = (
-        (dix.live_from[None, :] <= ts[:, None])
-        & (ts[:, None] <= dix.live_to[None, :])
-        & (dix.node_ct[None, :] <= te[:, None])
+        inside
+        & (rows(dix.live_from) <= ts[:, None])
+        & (ts[:, None] <= rows(dix.live_to))
+        & (rows(dix.node_ct) <= te[:, None])
     )
 
     # -- 4. pointer jumping along active parent links --------------------
-    pc = jnp.clip(parent, 0, N - 1)
+    pc = jnp.clip(parent, 0, W - 1)
     up = (parent >= 0) & active & jnp.take_along_axis(active, pc, axis=1)
-    top0 = jnp.where(up, pc, jnp.arange(N, dtype=jnp.int32)[None, :])
+    top0 = jnp.where(up, pc, jnp.arange(W, dtype=jnp.int32)[None, :])
 
     def body(state):
         top, _, rounds = state
@@ -485,13 +533,14 @@ def _component_masks(dix: DeviceIndex, e0_ok, e0c, ts, te):
         lambda s: s[1], body, (top0, jnp.array(True), jnp.int32(0)))
 
     # -- 5. membership: top[x] == top[entry_b], masked by activity -------
-    root = jnp.take_along_axis(top, e0c[:, None], axis=1)
-    member = active & (top == root) & e0_ok[:, None]
+    e0l = jnp.clip(e0c - start, 0, W - 1)
+    root = jnp.take_along_axis(top, e0l[:, None], axis=1)
+    member = (active & (top == root) & e0_ok[:, None]).astype(jnp.int32)
 
     out = jnp.zeros((B, n), jnp.int32)
-    rows = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, N))
-    out = out.at[rows, jnp.broadcast_to(dix.node_u[None, :], (B, N))].max(member.astype(jnp.int32))
-    out = out.at[rows, jnp.broadcast_to(dix.node_v[None, :], (B, N))].max(member.astype(jnp.int32))
+    bi = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, W))
+    out = out.at[bi, rows(dix.node_u)].max(member)
+    out = out.at[bi, rows(dix.node_v)].max(member)
     return out.astype(bool), rounds
 
 
@@ -517,7 +566,7 @@ def batch_query(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     if dix.num_nodes == 0:
         return jnp.zeros((B, dix.n), bool), jnp.int32(0)
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
-    return _component_masks(dix, e0_ok, e0c, ts, te)
+    return _component_masks(dix, u, e0_ok, e0c, ts, te)
 
 
 @jax.jit
@@ -534,7 +583,7 @@ def batch_query_full(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
         return (jnp.zeros((B, dix.n), bool),
                 jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
-    vmask, rounds = _component_masks(dix, e0_ok, e0c, ts, te)
+    vmask, rounds = _component_masks(dix, u, e0_ok, e0c, ts, te)
     return vmask, _version_member(dix, vmask, ts, te), rounds
 
 
@@ -558,7 +607,7 @@ def batch_query_full_mixed(dix: DeviceIndex, slot: jnp.ndarray,
                 jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[slot],
                               dix.vrow_ptr[slot + 1], ts, te)
-    vmask, rounds = _component_masks(dix, e0_ok, e0c, ts, te)
+    vmask, rounds = _component_masks(dix, slot, e0_ok, e0c, ts, te)
     vermask = (_version_member(dix, vmask, ts, te)
                & (dix.ver_k[None, :] == kq[:, None]))
     return vmask, vermask, rounds
@@ -624,7 +673,8 @@ def window_sweep(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     vlo = jnp.broadcast_to(dix.vrow_ptr[u], (W,))
     vhi = jnp.broadcast_to(dix.vrow_ptr[u + 1], (W,))
     e0_ok, e0c = _entry_nodes(dix, vlo, vhi, ts, te)
-    return _component_masks(dix, e0_ok, e0c, ts, te)
+    return _component_masks(dix, jnp.broadcast_to(u, (W,)), e0_ok, e0c,
+                            ts, te)
 
 
 def batch_query_np(index: PECBIndex, queries: list[tuple[int, int, int]]) -> list[set[int]]:

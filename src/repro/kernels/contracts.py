@@ -132,6 +132,7 @@ LAYOUT_CONTRACTS: dict[str, tuple[str, int]] = {
     "ver_ct": ("int32", 1),
     "ver_src": ("int32", 1),
     "ver_k": ("int32", 1),
+    "knode_ptr": ("int32", 1),
 }
 
 

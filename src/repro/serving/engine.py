@@ -702,8 +702,8 @@ class ServingEngine:
             store = handle.pecb.versions
             # single-k launch: carve the stratum's block out of the fused
             # mixed-k mirror (lazy per-handle memo) so sweep propagation
-            # pays for one stratum's nodes, not all |K|; ``u`` is a plain
-            # row of the sliced per-vertex CSR
+            # pays for one stratum's nodes, not the widest stratum's
+            # window; ``u`` is a plain row of the sliced per-vertex CSR
             sdix = handle.stratum_device(int(ws.k))
             for c0 in range(0, len(misses), cfg.max_batch):
                 chunk = misses[c0:c0 + cfg.max_batch]

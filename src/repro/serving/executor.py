@@ -79,9 +79,11 @@ class ShardedExecutor:
     Every launch runs as three live spans on the calling thread:
     ``executor.dispatch`` (pad, upload, enqueue, any compile),
     ``executor.wait`` (until the outputs are ready; carries the launch's
-    ``jump_rounds``) and ``executor.download`` (the masks and the round
-    count in one ``device_get``). The rounds add to the ``jump_rounds``
-    counter and the launch to ``jump_launches``, traced or not.
+    ``jump_rounds`` and ``jump_width``) and ``executor.download`` (the
+    masks and the round count in one ``device_get``). The rounds add to
+    the ``jump_rounds`` counter, the mirror's static node window
+    (``DeviceIndex.max_stratum_nodes``) to ``jump_width`` and the launch
+    to ``jump_launches``, traced or not.
     """
 
     def __init__(self, devices=None, *, metrics=None, tracer=None):
@@ -130,10 +132,10 @@ class ShardedExecutor:
         return self.tracer.span("executor.dispatch", program=program,
                                 bucket=bucket)
 
-    def _collect(self, out) -> list:
+    def _collect(self, out, dix: DeviceIndex) -> list:
         """Wait for a launch's outputs (masks..., rounds), then download
         them in one ``device_get``; returns the host masks and counts the
-        launch's pointer-jump rounds."""
+        launch's pointer-jump rounds and width."""
         with self.tracer.span("executor.wait") as wait:
             # repro: ignore[hot-path-transfer] — block_until_ready, the sync
             jax.block_until_ready(out)
@@ -142,9 +144,12 @@ class ShardedExecutor:
             # repro: ignore[hot-path-transfer] — device_get of masks + rounds
             *masks, rounds = jax.device_get(out)
         rounds = int(rounds)
+        width = dix.max_stratum_nodes
         wait.set("jump_rounds", rounds)
+        wait.set("jump_width", width)
         if self.metrics is not None:
             self.metrics.count("jump_rounds", rounds)
+            self.metrics.count("jump_width", width)
             self.metrics.count("jump_launches")
         return [np.asarray(m) for m in masks]
 
@@ -190,7 +195,7 @@ class ShardedExecutor:
                                        bucket)
             out = self._dispatch(batch_query, "batch_query", bucket,
                                  (dix, qu, qts, qte))
-        mask, = self._collect(out)
+        mask, = self._collect(out, dix)
         return mask[:b]
 
     def run_full(self, dix: DeviceIndex, u, ts, te,
@@ -204,7 +209,7 @@ class ShardedExecutor:
                                        bucket)
             out = self._dispatch(batch_query_full, "batch_query_full",
                                  bucket, (dix, qu, qts, qte))
-        vmask, vermask = self._collect(out)
+        vmask, vermask = self._collect(out, dix)
         return vmask[:b], vermask[:b, :dix.num_versions]
 
     def run_full_mixed(self, dix: DeviceIndex, slot, ts, te, kq,
@@ -234,7 +239,7 @@ class ShardedExecutor:
             out = self._dispatch(
                 batch_query_full_mixed, "batch_query_full_mixed", bucket,
                 (dix, qs, qts, qte, qkq))
-        vmask, vermask = self._collect(out)
+        vmask, vermask = self._collect(out, dix)
         return vmask[:b], vermask[:b, :dix.num_versions]
 
     def run_sweep(self, dix: DeviceIndex, u: int, ts, te,
@@ -250,7 +255,7 @@ class ShardedExecutor:
                                       bucket)
             out = self._dispatch(window_sweep, "window_sweep", bucket,
                                  (dix, jnp.int32(u), qts, qte))
-        mask, = self._collect(out)
+        mask, = self._collect(out, dix)
         return mask[:w]
 
     @staticmethod

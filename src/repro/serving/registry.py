@@ -173,7 +173,8 @@ class IndexHandle:
     def stratum_device(self, k: int) -> DeviceIndex:
         """Stratum ``k``'s block of :attr:`device` as a standalone per-k
         mirror (``batch_query.stratum_device``), so single-k launches pay
-        propagation on one stratum's nodes instead of all |K|. Memoized
+        propagation on one stratum's nodes instead of the fused mirror's
+        widest-stratum window. Memoized
         for the handle's lifetime — handles are immutable and swapped
         whole per epoch, so the memo can never go stale; the unlocked
         dict is a benign race (two threads may slice the same block, one

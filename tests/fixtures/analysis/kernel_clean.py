@@ -63,5 +63,5 @@ def tiny_layout(n_entries):
         "ent_ts": z, "ent_left": z, "ent_right": z, "ent_parent": z,
         "vrow_ptr": z, "vent_ts": z, "vent_node": z,
         "ver_ts_from": z, "ver_ts_to": z, "ver_ct": z,
-        "ver_src": z, "ver_k": z,
+        "ver_src": z, "ver_k": z, "knode_ptr": z,
     }

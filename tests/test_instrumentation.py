@@ -40,15 +40,21 @@ def _first_at_or_after(keys, lo, hi, t):
     return lo + int(np.searchsorted(keys[lo:hi], t, side="left"))
 
 
-def reference(index, vlo, vhi, ts, te, kq=None):
+def reference(index, slot, ts, te, kq=None):
     """(bool[B, n] vertex masks, bool[B, V] version masks, rounds) of one
-    launch over ``index``'s device layout: entry lookup, parent links at
-    ts, activity, pointer jumping to the batch's fixpoint (the last,
-    unchanged round counted), membership."""
+    launch over ``index``'s device layout: entry lookup at each query's
+    slot, parent links at ts, activity within the slot's stratum, pointer
+    jumping to the batch's fixpoint (the last, unchanged round counted),
+    membership."""
     meta, a = _host_layout(index)
     N, n = a["node_u"].shape[0], meta["n"]
     E = a["ent_ts"].shape[0]
     B = len(ts)
+    slot = np.asarray(slot)
+    vlo, vhi = a["vrow_ptr"][slot], a["vrow_ptr"][slot + 1]
+    kp = a["knode_ptr"]
+    x = np.arange(N)[None]
+    stratum = ((kp[slot // n][:, None] <= x) & (x < kp[slot // n + 1][:, None]))
     parent = np.empty((B, N), np.int64)
     for b in range(B):
         for x in range(N):
@@ -56,8 +62,8 @@ def reference(index, vlo, vhi, ts, te, kq=None):
                                    a["row_ptr"][x + 1], ts[b])
             parent[b, x] = a["ent_parent"][min(i, E - 1)]
     ts_c, te_c = np.asarray(ts)[:, None], np.asarray(te)[:, None]
-    active = ((a["live_from"][None] <= ts_c) & (ts_c <= a["live_to"][None])
-              & (a["node_ct"][None] <= te_c))
+    active = (stratum & (a["live_from"][None] <= ts_c)
+              & (ts_c <= a["live_to"][None]) & (a["node_ct"][None] <= te_c))
     pc = np.clip(parent, 0, N - 1)
     up = (parent >= 0) & active & np.take_along_axis(active, pc, axis=1)
     top = np.where(up, pc, np.arange(N)[None])
@@ -109,15 +115,13 @@ def test_round_counter_matches_numpy_reference(program, seed):
         dix = to_device(index)
         u = np.asarray([q[0] for q in qs], np.int32)
         out = batch_query_full(dix, jnp.asarray(u), tsd, ted)
-        vptr = _host_layout(index)[1]["vrow_ptr"]
-        vlo, vhi = vptr[u], vptr[u + 1]
+        slot = u
     elif program == "window_sweep":
         k = ks[0]
         index = sx.slice_k(k)
         u = qs[0][0]
         out = window_sweep(to_device(index), jnp.int32(u), tsd, ted)
-        vptr = _host_layout(index)[1]["vrow_ptr"]
-        vlo, vhi = [vptr[u]] * len(qs), [vptr[u + 1]] * len(qs)
+        slot = [u] * len(qs)
     else:
         index = sx
         slot = mixed_slots(sx, [(q[0], k) for q, k in zip(qs, ks)])
@@ -128,9 +132,7 @@ def test_round_counter_matches_numpy_reference(program, seed):
             kq = ks
             out = batch_query_full_mixed(dix, jnp.asarray(slot), tsd, ted,
                                          jnp.asarray(kq, jnp.int32))
-        vptr = _host_layout(index)[1]["vrow_ptr"]
-        vlo, vhi = vptr[slot], vptr[slot + 1]
-    want_v, want_ver, want_rounds = reference(index, vlo, vhi, ts, te, kq)
+    want_v, want_ver, want_rounds = reference(index, slot, ts, te, kq)
     *masks, rounds = jax.device_get(out)
     assert rounds.dtype == np.int32 and rounds.shape == ()
     assert int(rounds) == want_rounds >= 1
@@ -175,15 +177,22 @@ def test_jump_counters_add_up_across_launches():
         slot = mixed_slots(sx, [(q[0], 2) for q in qs])
         ts, te = _windows(g, qs)
         ex.run(dix, slot, ts, te, 8)
-        per_launch.append(reference(
-            sx, _host_layout(sx)[1]["vrow_ptr"][slot],
-            _host_layout(sx)[1]["vrow_ptr"][slot + 1], ts, te)[2])
-    ex.run_sweep(to_device(sx.slice_k(2)), 3, [1, 2, 3], [5, 6, 7], 8)
+        per_launch.append(reference(sx, slot, ts, te)[2])
+    sweep_dix = to_device(sx.slice_k(2))
+    ex.run_sweep(sweep_dix, 3, [1, 2, 3], [5, 6, 7], 8)
     waits = tracer.spans(name="executor.wait")
     rounds = [s.attrs["jump_rounds"] for s in waits]
     assert rounds[:3] == per_launch
     assert metrics.counter("jump_launches") == 4
     assert metrics.counter("jump_rounds") == sum(rounds)
+    # each launch adds its static window once: the fused mirror's widest
+    # stratum, and the whole of the one-stratum sweep mirror
+    widest = int(np.diff(sx.knode_ptr).max())
+    assert dix.max_stratum_nodes == widest < dix.num_nodes
+    assert sweep_dix.max_stratum_nodes == sweep_dix.num_nodes
+    assert [s.attrs["jump_width"] for s in waits] == [widest] * 3 + [
+        sweep_dix.num_nodes]
+    assert metrics.counter("jump_width") == 3 * widest + sweep_dix.num_nodes
     # three live spans per launch, in order, on this thread
     names = [s.name for s in tracer.spans() if s.name.startswith("executor.")]
     assert names == ["executor.dispatch", "executor.wait",

@@ -6,8 +6,8 @@ cache keys, and result-cache purging on index eviction."""
 import numpy as np
 import pytest
 
-from repro.core.batch_query import (batch_query_edges_np, batch_query_np,
-                                    to_device, window_sweep)
+from repro.core.batch_query import (batch_query, batch_query_edges_np,
+                                    batch_query_np, to_device, window_sweep)
 from repro.core.core_time import edge_core_times
 from repro.core.ctmsf_index import CTMSFIndex
 from repro.core.ef_index import EFIndex
@@ -137,6 +137,21 @@ class TestDeviceModes:
         for (u, ts, te), ev, vv in zip(qs, got_e, got_v):
             assert ev == tccs_oracle_edges(g, k, u, ts, te), (u, ts, te)
             assert vv == tccs_oracle(g, k, u, ts, te), (u, ts, te)
+
+    def test_per_k_mirror_window_is_its_whole_forest(self, stack):
+        """The control of the stratum window: a per-k mirror is one
+        stratum, so every launch propagates over all of its nodes."""
+        import jax
+        import jax.numpy as jnp
+        from test_stratified import _jaxpr_shapes
+        g, k, pecb, *_ = stack
+        dix = to_device(pecb)
+        N = pecb.num_nodes
+        assert dix.max_stratum_nodes == dix.num_nodes == N
+        assert np.asarray(dix.knode_ptr).tolist() == [0, N]
+        q = jnp.zeros((8,), jnp.int32)
+        shapes = _jaxpr_shapes(jax.make_jaxpr(batch_query)(dix, q, q, q).jaxpr)
+        assert (8, N) in shapes
 
     def test_engine_device_route_edge_modes(self, stack):
         g, k, *_ = stack

@@ -14,9 +14,13 @@ import warnings
 import numpy as np
 import pytest
 
-from repro.core.batch_query import (batch_query, batch_query_full_mixed,
-                                    mixed_slots, stratum_device, to_device,
-                                    window_sweep)
+import jax
+import jax.numpy as jnp
+
+from repro.core.batch_query import (_ARRAY_FIELDS, batch_query,
+                                    batch_query_full,
+                                    batch_query_full_mixed, mixed_slots,
+                                    stratum_device, to_device, window_sweep)
 from repro.core.core_time import (default_ks, extend_stratified_core_times,
                                   shrink_stratified_core_times,
                                   stratified_core_times)
@@ -30,8 +34,22 @@ from repro.core.streaming import (extend_stratified_index,
 from repro.core.temporal_graph import gen_temporal_graph, random_queries
 from repro.serving import EngineConfig, IndexRegistry, ServingEngine
 from repro.serving.cache import ResultCache
+from repro.serving.executor import pad_queries
 
 from test_streaming import assert_pecb_identical
+
+
+def _jaxpr_shapes(jaxpr) -> set:
+    """Every shape a jaxpr and its sub-jaxprs (jit, while) compute."""
+    shapes = set()
+    for eqn in jaxpr.eqns:
+        shapes.update(tuple(v.aval.shape) for v in eqn.outvars)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    shapes |= _jaxpr_shapes(inner)
+    return shapes
 
 
 def graphs():
@@ -106,40 +124,66 @@ class TestThreeBackendEquality:
 # ----------------------------------------------------------------------
 
 class TestMixedKDevice:
-    def test_vertex_masks_match_host_per_slot(self):
-        g = graphs()[1]
+    @pytest.mark.parametrize("gi", [1, 2])
+    @pytest.mark.parametrize("program", ["batch_query",
+                                         "batch_query_full_mixed"])
+    def test_mixed_k_batch_matches_each_stratum(self, program, gi):
+        # one launch mixes every stratum, the widest (smallest k) and the
+        # last (largest k, whose window is moved left to fit the mirror),
+        # plus inert pad lanes; each row answers from its own stratum
+        g = graphs()[gi]
         sx = build_stratified_index(g)
         dix = to_device(sx)
-        rng = np.random.default_rng(7)
-        qs = random_queries(g, 32, seed=7)
-        ks = [int(rng.choice(sx.supported_ks)) for _ in qs]
+        sizes = np.diff(sx.knode_ptr)
+        W = dix.max_stratum_nodes
+        assert W == sizes[0] == sizes.max() < dix.num_nodes
+        assert sx.knode_ptr[-2] + W > dix.num_nodes
+        qs = random_queries(g, 29, seed=7 + gi)
+        ks = [sx.supported_ks[i % len(sx.supported_ks)]
+              for i in range(len(qs))]
         slot = mixed_slots(sx, [(u, k) for (u, _, _), k in zip(qs, ks)])
         ts = np.asarray([q[1] for q in qs], np.int32)
         te = np.asarray([q[2] for q in qs], np.int32)
-        vmask = np.asarray(batch_query(dix, slot, ts, te)[0])
+        slot, ts, te = pad_queries(slot, ts, te, 32)
+        kq = np.zeros(32, np.int32)
+        kq[:len(ks)] = ks
+        if program == "batch_query":
+            vmask = np.asarray(batch_query(dix, slot, ts, te)[0])
+        else:
+            vmask, vermask, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
+            vmask, vermask = np.asarray(vmask), np.asarray(vermask)
+            assert not vermask[len(qs):].any()
+        assert not vmask[len(qs):].any()
+        store = sx.versions
         for i, ((u, a, b), k) in enumerate(zip(qs, ks)):
             want = sx.slice_k(k)._component_vertices(u, a, b)
             assert frozenset(np.nonzero(vmask[i])[0].tolist()) == \
                 frozenset(want), (u, a, b, k)
+            if program == "batch_query_full_mixed":
+                got = {int(store.edge_id[j])
+                       for j in np.nonzero(vermask[i])[0].tolist()}
+                assert got == tccs_oracle_edges(g, k, u, a, b), (u, a, b, k)
 
-    def test_full_mixed_version_mask_filters_by_stratum(self):
-        g = graphs()[1]
+    @pytest.mark.parametrize("program", ["batch_query", "batch_query_full",
+                                         "batch_query_full_mixed",
+                                         "window_sweep"])
+    def test_fused_programs_hold_no_all_strata_row(self, program):
+        # every (B, .) array of a launch on the fused mirror is one
+        # stratum window wide: none spans the nodes of all strata
+        g = graphs()[2]
         sx = build_stratified_index(g)
         dix = to_device(sx)
-        store = sx.versions
-        rng = np.random.default_rng(8)
-        qs = random_queries(g, 16, seed=8)
-        ks = [int(rng.choice(sx.supported_ks)) for _ in qs]
-        slot = mixed_slots(sx, [(u, k) for (u, _, _), k in zip(qs, ks)])
-        ts = np.asarray([q[1] for q in qs], np.int32)
-        te = np.asarray([q[2] for q in qs], np.int32)
-        kq = np.asarray(ks, np.int32)
-        _, vermask, _ = batch_query_full_mixed(dix, slot, ts, te, kq)
-        vermask = np.asarray(vermask)
-        for i, ((u, a, b), k) in enumerate(zip(qs, ks)):
-            got = {int(store.edge_id[j])
-                   for j in np.nonzero(vermask[i])[0].tolist()}
-            assert got == tccs_oracle_edges(g, k, u, a, b), (u, a, b, k)
+        B, N, W = 16, dix.num_nodes, dix.max_stratum_nodes
+        q = jnp.zeros((B,), jnp.int32)
+        fn = {"batch_query": batch_query, "batch_query_full": batch_query_full,
+              "batch_query_full_mixed": batch_query_full_mixed,
+              "window_sweep": window_sweep}[program]
+        args = (dix, q, q, q, q) if program == "batch_query_full_mixed" \
+            else (dix, q, q, q)
+        shapes = _jaxpr_shapes(jax.make_jaxpr(fn)(*args).jaxpr)
+        assert (B, W) in shapes
+        assert not any(len(sh) == 2 and sh[1] in (N, N + 1)
+                       for sh in shapes), program
 
     def test_window_sweep_slot_selects_stratum(self):
         g = graphs()[0]
@@ -167,17 +211,16 @@ class TestMixedKDevice:
         ts = np.asarray([w[0] for w in windows], np.int32)
         te = np.asarray([w[1] for w in windows], np.int32)
         u = 1
-        arrays = ("node_u", "node_v", "node_ct", "live_from", "live_to",
-                  "row_ptr", "ent_ts", "ent_left", "ent_right", "ent_parent",
-                  "vrow_ptr", "vent_ts", "vent_node", "ver_ts_from",
-                  "ver_ts_to", "ver_ct", "ver_src", "ver_k")
         for k in sx.supported_ks:
             sd = stratum_device(dix, sx, k)
             ref = to_device(sx.slice_k(k))
-            for f in arrays:
+            for f in _ARRAY_FIELDS:
                 assert np.array_equal(np.asarray(getattr(sd, f)),
                                       np.asarray(getattr(ref, f))), (k, f)
             assert sd.num_versions == ref.num_versions
+            # one stratum: the node window is the whole slice
+            assert sd.max_stratum_nodes == ref.max_stratum_nodes \
+                == ref.num_nodes == int(np.diff(sx.knode_ptr)[sx.k_index(k)])
             slot = np.full(len(windows), sx.k_index(k) * g.n + u, np.int32)
             fused = np.asarray(window_sweep(dix, slot, ts, te)[0])
             sliced = np.asarray(window_sweep(
