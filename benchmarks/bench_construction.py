@@ -9,7 +9,7 @@ self-contained:
   (``edge_core_times(engine="legacy")``) and the per-version Python insert
   loop (``IncrementalBuilder(prefilter=False)``).
 * ``batched`` — the PR-2 plane: precomputed pair-CSR/t_uv sweep engine
-  (host or jitted JAX, ``engine="auto"``), MSF-prefiltered builder, and the
+  (the host engine, ``engine="auto"``), MSF-prefiltered builder, and the
   lexsort ``pack_index``.
 
 The two planes are asserted to produce identical ``CoreTimeTable``s (all

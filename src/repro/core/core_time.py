@@ -38,8 +38,10 @@ window only raises core times):
 * ``engine="jax"`` — one jitted launch sweeps a whole block of start times
   (`lax.scan` over ts, warm carry across blocks); the inner op is the
   counting-bisection segmented select, with a `lax.cond`-gated climb so
-  converged start times pay a single verification pass. This is the
-  device-plane path (Pallas counter selectable via ``use_pallas``).
+  converged start times pay a single verification pass (Pallas counter
+  selectable via ``use_pallas``). Only called by name: on a TPU v5e the
+  host sweep builds the same table several times faster
+  (`resolve_engine`).
 * ``engine="legacy"`` — the seed's per-ts numpy lexsort loop, kept as the
   differential-testing oracle and the PR-1 benchmark baseline.
 
@@ -332,7 +334,7 @@ def _compress(g: TemporalGraph, vct: np.ndarray,
 
 
 # ----------------------------------------------------------------------
-# Host engine: vectorized numpy sweep (default on CPU-only backends)
+# Host engine: vectorized numpy sweep (the default on every backend)
 # ----------------------------------------------------------------------
 
 def _sweep_host(g: TemporalGraph, k: int) -> np.ndarray:
@@ -384,7 +386,7 @@ def _sweep_host(g: TemporalGraph, k: int) -> np.ndarray:
 
 
 # ----------------------------------------------------------------------
-# JAX engine: jitted multi-start-time sweep (device plane)
+# JAX engine: jitted multi-start-time sweep (by name only)
 # ----------------------------------------------------------------------
 
 def _count_le_pallas(w, thr, seg, vptr):
@@ -517,24 +519,29 @@ ENGINES = ("auto", "host", "jax", "jax_pallas", "legacy")
 
 def resolve_engine(engine: str = "auto") -> str:
     """The engine a core-time build runs for ``engine``: ``"auto"`` is the
-    jitted sweep on a non-CPU JAX backend and the host sweep on the CPU."""
+    host sweep on every backend.
+
+    On one TPU v5e at CollegeMsg's counts (36 strata, PERF.md §6) the
+    fused host sweep built the stratified table in 11.1 s, compression
+    included; the jitted sweep took 75.4 s of device time, 82.7 s in all
+    with its programs cached and 141.6 s cold, when its 37 ``_sweep_block``
+    programs compile. The jitted engines stay callable by name."""
     if engine not in ENGINES:
         raise ValueError(f"unknown engine {engine!r}, expected one of {ENGINES}")
-    if engine == "auto":
-        return "jax" if jax.default_backend() != "cpu" else "host"
-    return engine
+    return "host" if engine == "auto" else engine
 
 
 def edge_core_times(g: TemporalGraph, k: int, *,
                     engine: str = "auto") -> CoreTimeTable:
     """Compute CT(e)_ts for every edge and start time, delta-compressed.
 
-    ``engine="auto"`` picks the jitted sweep when a non-CPU JAX backend is
-    present and the vectorized host sweep otherwise (XLA CPU lowers the
-    sweep's sorts/scans poorly; the host engine is the same formulation in
-    numpy). ``"jax_pallas"`` is the jitted sweep with the Pallas tile
-    counter as the selection inner op (compiled on device backends,
-    interpreted on CPU). All engines return bit-identical tables.
+    ``engine="auto"`` is the vectorized host sweep on every backend: XLA
+    on the CPU lowers the sweep's sorts/scans poorly, and on a TPU v5e the
+    host sweep beat the jitted one 6.8x on device time alone
+    (`resolve_engine`, PERF.md §6). ``"jax"`` is the jitted sweep and
+    ``"jax_pallas"`` the same with the Pallas tile counter as the
+    selection inner op (compiled on device backends, interpreted on CPU).
+    All engines return bit-identical tables.
     """
     engine = resolve_engine(engine)
     if engine == "legacy":
@@ -1057,7 +1064,10 @@ def stratified_core_times(g: TemporalGraph, ks=None, *,
     Every stratum is bit-identical to ``edge_core_times(g, k)`` — the
     host path runs the fused warm-seeded sweep `_sweep_host_stratified`;
     the jitted engines run per-k sweeps (`_sweep_jax`); ``legacy`` is the
-    differential-testing oracle. ``timings`` (a dict) receives the
+    differential-testing oracle. ``"auto"`` is the host path on every
+    backend: on a TPU v5e at CollegeMsg's counts it took 11.1 s against
+    the jitted sweep's 82.7 s (`resolve_engine`, PERF.md §6).
+    ``timings`` (a dict) receives the
     :class:`SweepStages` sub-stage seconds, and each sub-stage run is a
     live child span of ``span`` when one is given.
     """

@@ -192,6 +192,29 @@ class TestConstructionEngines:
         for f in ("edge_id", "ts_from", "ts_to", "ct", "vertex_ct"):
             assert np.array_equal(getattr(host, f), getattr(pallas, f)), f
 
+    def test_auto_is_the_host_sweep_on_a_tpu_backend(self, monkeypatch):
+        """On a TPU-reporting backend the default build runs the host sweep:
+        no jitted sweep is dispatched or compiled, and the table is the
+        jitted engine's, bit for bit."""
+        import dataclasses
+        import jax
+        from repro.core import core_time
+
+        g = gen_temporal_graph(n=30, m=180, t_max=14, seed=4)
+        ks = (2, 3)
+        want = core_time.stratified_core_times(g, ks, engine="jax")
+        compiled = core_time._sweep_block._cache_size()
+        timings = {}
+        with monkeypatch.context() as m:
+            m.setattr(jax, "default_backend", lambda: "tpu")
+            assert core_time.resolve_engine("auto") == "host"
+            got = core_time.stratified_core_times(g, ks, timings=timings)
+        assert timings["core_times.dispatch"] == 0
+        assert core_time._sweep_block._cache_size() == compiled
+        for f in dataclasses.fields(want):
+            assert np.array_equal(getattr(got, f.name),
+                                  getattr(want, f.name)), f.name
+
     def test_self_loops_do_not_corrupt_builder(self):
         """Directly-constructed graphs may carry self-loops (from_edges
         drops them); the builder must treat them as degenerate on both
