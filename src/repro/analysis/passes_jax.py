@@ -48,7 +48,8 @@ from .core import (AnalysisConfig, Finding, Module, iter_symbols,
 #: aux_data of registered pytrees (DeviceIndex & co), safe to branch on.
 STATIC_ATTRS = frozenset({
     "num_nodes", "n", "t_max", "max_node_entries", "max_vert_entries",
-    "num_versions", "max_stratum_nodes", "ndim", "dtype", "shape",
+    "num_versions", "max_stratum_nodes", "max_live_nodes", "ndim", "dtype",
+    "shape",
 })
 
 _HOST_SYNC_DOTTED = {

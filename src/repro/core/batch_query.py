@@ -8,8 +8,8 @@ packed PECB arrays:
 1. **Entry points** — the paper's per-vertex lookup (Alg 1 line 3) becomes a
    vectorized lower-bound binary search over the per-vertex version CSR.
 2. **Link resolution** — the paper's per-node binary search (Alg 1 line 10)
-   becomes a ``(B, W)`` vectorized lower-bound over the per-node entry CSR:
-   for every query b and forest node x of its window we resolve x's parent
+   becomes a ``(B, M)`` vectorized lower-bound over the per-node entry CSR:
+   for every query b and forest node x of its live list we resolve x's parent
    at ``ts_b`` in ``O(log t̄)`` steps, all queries and nodes in parallel.
 3. **Traversal** — BFS becomes pointer jumping. The ts-forest's parent and
    child links agree, so the active nodes of one component form one subtree
@@ -19,20 +19,24 @@ packed PECB arrays:
    gather per round, O(log depth) rounds, the fixpoint detected by a
    ``lax.while_loop``. Every program returns the launch's round count as
    one more ``int32`` scalar output, downloaded with the masks.
-   Steps 2 and 3 run over each query's **stratum window**, not over every
-   node of the mirror: on the fused k-stratified mirror a query at k can
-   only reach stratum k's nodes (the strata are link-disjoint), so row b
-   reads the ``W = max_stratum_nodes`` contiguous nodes that start at its
-   stratum's first node ``knode_ptr[slot_b // n]``, masks the nodes of
-   other strata in that window as inactive, and pointer-jumps in
-   window-local ids. The work per row is ``(B, W)`` with W the widest
-   stratum; on a per-k mirror W is every node.
+   Steps 2 and 3 run over each query's **live list**, not over every
+   node of the mirror: at one start time ts the nodes of stratum k whose
+   lifetime ``[live_from, live_to]`` holds ts form a spanning forest of
+   its vertices, and every other node is a self-loop that can never be a
+   member. The layout keeps these lists as one CSR by (stratum, start
+   time) (``live_ptr``/``live_node``), so row b reads the
+   ``M = max_live_nodes`` ids of row ``(slot_b // n, ts_b)``, masks the
+   lanes past the row's count, maps parent links to list positions and
+   pointer-jumps over list positions. The work per row is ``(B, M)``,
+   with M the longest list: under n, where a stratum window holds tens
+   of thousands of nodes.
 
-Node activity masking uses the forest-membership lifetimes recorded by the
+Node activity uses the forest-membership lifetimes recorded by the
 builder: a node participates for query b iff
-``live_from <= ts_b <= live_to`` and ``ct <= te_b``. This is what makes the
-stale entries of expired nodes harmless here (the host DFS never reaches
-them; the data-parallel propagation must mask them explicitly).
+``live_from <= ts_b <= live_to`` (it is on b's live list) and
+``ct <= te_b``. This is what makes the stale entries of expired nodes
+harmless here (the host DFS never reaches them; the data-parallel
+propagation must leave them out explicitly).
 
 Query API v2 additions (DESIGN.md §8):
 
@@ -130,10 +134,15 @@ class DeviceIndex:
     ver_k: jnp.ndarray        # per-version stratum k (constant per-k mirror)
     # int32[|K|+1]: first node of each stratum ([0, N] on a per-k mirror)
     knode_ptr: jnp.ndarray
+    # live-node CSR (:func:`_live_csr`): row ki*(t_max+2)+t lists stratum
+    # ki's nodes live at start time t, ascending global ids
+    live_ptr: jnp.ndarray     # int32[|K|*(t_max+2)+1]
+    live_node: jnp.ndarray    # int32[L], padded to length >= 1
     max_node_entries: int     # static: longest per-node entry list
     max_vert_entries: int     # static: longest per-vertex entry list
     num_versions: int         # static: true version count (pre-padding)
-    max_stratum_nodes: int    # static: widest stratum, the node window W
+    max_stratum_nodes: int    # static: widest stratum W
+    max_live_nodes: int       # static: longest live list M, at least 1
 
     @property
     def num_nodes(self) -> int:
@@ -145,9 +154,10 @@ _ARRAY_FIELDS = (
     "row_ptr", "ent_ts", "ent_left", "ent_right", "ent_parent",
     "vrow_ptr", "vent_ts", "vent_node",
     "ver_ts_from", "ver_ts_to", "ver_ct", "ver_src", "ver_k", "knode_ptr",
+    "live_ptr", "live_node",
 )
 _META_FIELDS = ("n", "t_max", "max_node_entries", "max_vert_entries",
-                "num_versions", "max_stratum_nodes")
+                "num_versions", "max_stratum_nodes", "max_live_nodes")
 
 jax.tree_util.register_pytree_node(
     DeviceIndex,
@@ -156,6 +166,35 @@ jax.tree_util.register_pytree_node(
     lambda meta, arrs: DeviceIndex(**dict(zip(_META_FIELDS, meta)),
                                    **dict(zip(_ARRAY_FIELDS, arrs))),
 )
+
+
+def _live_csr(live_from, live_to, knode_ptr, t_max):
+    """Each stratum's forest nodes live at each start time, as one CSR.
+
+    Row ``ki * (t_max + 2) + t``, for t = 0 .. t_max + 1, lists in
+    ascending id the nodes x of stratum ki (``knode_ptr[ki] <= x <
+    knode_ptr[ki + 1]``) with ``live_from[x] <= t <= live_to[x]``. At one
+    start time the live nodes of a stratum are a spanning forest of its
+    vertices, so a row holds fewer than n of them. Lifetimes lie in
+    ``[1, t_max]`` (raises ``ValueError`` otherwise), so the two end rows
+    are empty and any start time outside the index reads an empty row
+    once clipped into ``[0, t_max + 1]``. Returns int64 ``(ptr, node)``."""
+    lf = np.asarray(live_from, np.int64)
+    lt = np.asarray(live_to, np.int64)
+    T2 = int(t_max) + 2
+    K = len(knode_ptr) - 1
+    span = np.maximum(lt - lf + 1, 0)
+    held = span > 0
+    if held.any() and (lf[held].min() < 1 or lt[held].max() > t_max):
+        raise ValueError("a forest-node lifetime leaves [1, t_max]")
+    base = np.repeat(np.arange(K, dtype=np.int64) * T2, np.diff(knode_ptr))
+    node = np.repeat(np.arange(lf.size, dtype=np.int64), span)
+    # the row of node x at its p-th live start time is base + lf + p
+    run0 = np.cumsum(span) - span
+    row = np.repeat(base + lf - run0, span) + np.arange(node.size)
+    ptr = np.zeros(K * T2 + 1, np.int64)
+    np.cumsum(np.bincount(row, minlength=K * T2), out=ptr[1:])
+    return ptr, node[np.argsort(row, kind="stable")]
 
 
 def _host_layout(index):
@@ -175,6 +214,8 @@ def _host_layout(index):
     has_vers = store is not None and store.num_versions > 0
     pad0 = np.zeros((1,), np.int32)
     padn = np.full((1,), NONE, np.int32)
+    live_ptr, live_node = _live_csr(index.node_live_from, index.node_live_to,
+                                    [0, index.num_nodes], index.t_max)
     arrays = {
         "node_u": i32(index.node_u),
         "node_v": i32(index.node_v),
@@ -196,6 +237,8 @@ def _host_layout(index):
         "ver_k": (np.full(store.num_versions, index.k, np.int32)
                   if has_vers else pad0),
         "knode_ptr": i32([0, index.num_nodes]),
+        "live_ptr": _i32(live_ptr, "live-node row pointer"),
+        "live_node": i32(live_node) if live_node.size else pad0,
     }
     meta = {
         "n": index.n,
@@ -204,6 +247,7 @@ def _host_layout(index):
         "max_vert_entries": int(vseg.max()) if vseg.size else 0,
         "num_versions": store.num_versions if has_vers else 0,
         "max_stratum_nodes": index.num_nodes,
+        "max_live_nodes": max(int(np.diff(live_ptr).max()), 1),
     }
     return meta, arrays
 
@@ -218,8 +262,9 @@ def _host_layout_stratified(sx: StratifiedPECB):
     strata stay link-disjoint, so :func:`batch_query`'s pointer jumping
     serves a mixed-k batch unchanged — per-query k enters only
     as the host-computed entry slot, from which the device reads the
-    query's stratum (``knode_ptr[slot // n]``) and propagates over that
-    stratum's node window alone (:func:`_component_masks`), plus the
+    query's stratum (``slot // n``) and propagates over that stratum's
+    nodes live at the query's start time alone (:func:`_component_masks`),
+    plus the
     ``ver_k == kq`` filter of :func:`batch_query_full_mixed` (the version
     arrays are the one place where records of different strata share an
     index space).
@@ -261,6 +306,9 @@ def _host_layout_stratified(sx: StratifiedPECB):
     vseg = np.diff(vrow_ptr)
     pad0 = np.zeros((1,), np.int32)
     padn = np.full((1,), NONE, np.int32)
+    live_ptr, live_node = _live_csr(sx.node_live_from, sx.node_live_to,
+                                    sx.knode_ptr, sx.t_max)
+    live_seg = np.diff(live_ptr)
     arrays = {
         "node_u": i32(sx.node_u),
         "node_v": i32(sx.node_v),
@@ -283,6 +331,8 @@ def _host_layout_stratified(sx: StratifiedPECB):
                             np.diff(st.kptr)).astype(np.int32)
                   if V else pad0),
         "knode_ptr": _i32(sx.knode_ptr, "stratum node pointer"),
+        "live_ptr": _i32(live_ptr, "live-node row pointer"),
+        "live_node": i32(live_node) if live_node.size else pad0,
     }
     knodes = np.diff(sx.knode_ptr)
     meta = {
@@ -292,6 +342,7 @@ def _host_layout_stratified(sx: StratifiedPECB):
         "max_vert_entries": int(vseg.max()) if vseg.size else 0,
         "num_versions": V,
         "max_stratum_nodes": int(knodes.max()) if knodes.size else 0,
+        "max_live_nodes": max(int(live_seg.max()) if live_seg.size else 0, 1),
     }
     return meta, arrays
 
@@ -365,21 +416,27 @@ def stratum_device(dix: DeviceIndex, sx: StratifiedPECB,
                    k: int) -> DeviceIndex:
     """Carve ONE stratum's block out of a fused stratified device mirror.
 
-    A single-k program (the window sweep) pays propagation cost on a
-    node window as wide as the mirror's widest stratum — on the fused
-    mixed-k mirror, the k = 2 stratum's width for a launch that can only
-    ever touch stratum k. This slices the ``[knode_ptr[ki],
+    A single-k program (the window sweep) pays propagation cost on live
+    lists as long as the mirror's longest — on the fused mixed-k mirror,
+    the k = 2 stratum's for a launch that can only ever touch stratum k.
+    This slices the ``[knode_ptr[ki],
     knode_ptr[ki+1])`` node block plus its entry / vertex-entry / version
     segments into a standalone per-k :class:`DeviceIndex` (a handful of
-    eager device slices, no host round trip), with forest-node links
-    rebased into the block's local id space. Array-for-array equal to
-    ``to_device(sx.slice_k(k))`` (test-asserted); the static
-    ``max_*_entries`` meta keeps the fused mirror's values — a valid
-    upper bound costing at most a few extra binary-search steps.
+    eager device slices and one download of the stratum's live-row
+    pointers), with forest-node links rebased into the block's local id
+    space. Array-for-array equal to ``to_device(sx.slice_k(k))``
+    (test-asserted); the static ``max_*_entries`` meta keeps the fused
+    mirror's values — a valid upper bound costing at most a few extra
+    binary-search steps — while ``max_live_nodes`` is the stratum's own
+    longest live list.
     """
     ki = sx.k_index(k)
     n = dix.n
     nlo, nhi = int(sx.knode_ptr[ki]), int(sx.knode_ptr[ki + 1])
+    T2 = dix.t_max + 2
+    live_ptr = dix.live_ptr[ki * T2:(ki + 1) * T2 + 1]
+    own_ptr = np.asarray(live_ptr)
+    plo, phi = int(own_ptr[0]), int(own_ptr[-1])
     elo, ehi = int(sx.kent_ptr[ki]), int(sx.kent_ptr[ki + 1])
     vlo, vhi = int(sx.kvent_ptr[ki]), int(sx.kvent_ptr[ki + 1])
     st = sx.strata
@@ -419,10 +476,13 @@ def stratum_device(dix: DeviceIndex, sx: StratifiedPECB,
         ver_src=dix.ver_src[slo:shi] if has_ver else pad0,
         ver_k=dix.ver_k[slo:shi] if has_ver else pad0,
         knode_ptr=jnp.asarray([0, nhi - nlo], jnp.int32),
+        live_ptr=live_ptr - plo,
+        live_node=dix.live_node[plo:phi] - nlo if phi > plo else pad0,
         max_node_entries=dix.max_node_entries,
         max_vert_entries=dix.max_vert_entries,
         num_versions=shi - slo,
         max_stratum_nodes=nhi - nlo,
+        max_live_nodes=max(int(np.diff(own_ptr).max()), 1),
     )
 
 
@@ -441,6 +501,13 @@ def _lower_bound(ts_arr: jnp.ndarray, lo: jnp.ndarray, hi: jnp.ndarray,
         lo = jnp.where(go_right & (lo < hi), mid + 1, lo)
         hi = jnp.where((~go_right) & (lo < hi), mid, hi)
     return lo
+
+
+def _first_day(ts):
+    """Start times moved up to 1: no edge precedes time 1, so a window
+    that starts earlier holds the edges of the one that starts at 1
+    (Algorithm 1 and the oracle answer both alike)."""
+    return jnp.maximum(ts, 1)
 
 
 def _entry_steps(dix: DeviceIndex) -> tuple[int, int]:
@@ -469,15 +536,17 @@ def _component_masks(dix: DeviceIndex, slot, e0_ok, e0c, ts, te):
     """Steps 2-5: per-(query, node) parent resolution, activity masking,
     pointer jumping to each component's top node, membership collection.
 
-    Each query works on its own stratum's node window of static width
-    ``W = dix.max_stratum_nodes``: row b reads ``W`` contiguous nodes from
-    its stratum's first node ``knode_ptr[slot_b // n]`` (moved left where
-    that would run past the mirror's end, since a dynamic slice clamps its
-    start) and masks the nodes outside its stratum as inactive. The strata
-    are link-disjoint, so the entry node and every parent link a member
-    can follow lie in the window, and propagation runs in window-local
-    ids. On a mirror of one stratum ``W`` is every node and the window is
-    the whole mirror.
+    Each query works on its **live list**: the nodes of its stratum that
+    are live at its start time, row ``ki * (t_max + 2) + clip(ts_b, 0,
+    t_max + 1)`` of the live-node CSR with ``ki = slot_b // n``. Row b
+    reads ``M = dix.max_live_nodes`` contiguous ids from the row's start
+    (moved left where that would run past the array's end, since a
+    dynamic slice clamps its start) and masks the lanes outside the row.
+    Every other node of the stratum is a self-loop at ts_b, so dropping
+    it changes no component and no round count. Parent links and the
+    entry node map to list positions through a stratum-local ``(B, W)``
+    buffer (``W = dix.max_stratum_nodes``) that holds each live node's
+    lane and -1 elsewhere: a parent that is not live is no link.
 
     Returns ``(bool[B, n] vertex mask, int32 rounds)``: forest-node
     membership is ``top[x] == top[entry_b]`` (masked by activity),
@@ -485,44 +554,50 @@ def _component_masks(dix: DeviceIndex, slot, e0_ok, e0c, ts, te):
     pointer-jump rounds the batch took, the last one (which finds no
     change) included."""
     B = ts.shape[0]
-    N = dix.num_nodes
     W = dix.max_stratum_nodes
+    M = dix.max_live_nodes
+    L = dix.live_node.shape[0]
     n = dix.n
+    T2 = dix.t_max + 2
     _, nsteps = _entry_steps(dix)
 
-    # -- each row's node window [start_b, start_b + W) -------------------
+    # -- each row's live list: lanes [lo_b, hi_b) of [start_b, start_b+M) --
     ki = slot // n
-    first = dix.knode_ptr[ki]
-    start = jnp.minimum(first, N - W)
+    row = ki * T2 + jnp.clip(ts, 0, T2 - 1)
+    first = dix.live_ptr[row]
+    start = jnp.minimum(first, L - M)
     lo = (first - start)[:, None]
-    hi = lo + (dix.knode_ptr[ki + 1] - first)[:, None]
-    local = jnp.arange(W, dtype=jnp.int32)[None, :]
-    inside = (lo <= local) & (local < hi)
+    hi = lo + (dix.live_ptr[row + 1] - first)[:, None]
+    lane = jnp.arange(M, dtype=jnp.int32)[None, :]
+    live = (lo <= lane) & (lane < hi)
+    ids = jax.vmap(
+        lambda s: jax.lax.dynamic_slice(dix.live_node, (s,), (M,)))(start)
 
-    def rows(a, w=W):
-        return jax.vmap(lambda s: jax.lax.dynamic_slice(a, (s,), (w,)))(start)
+    # -- node id -> lane of the row's list, -1 where not live at ts ------
+    base = dix.knode_ptr[ki][:, None]
+    bi = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, M))
+    lane_of = jnp.full((B, W), NONE, jnp.int32).at[
+        bi, jnp.where(live, ids - base, W)].set(
+            jnp.broadcast_to(lane, (B, M)), mode="drop")
 
-    # -- 2. per-(query, node) parent link at ts --------------------------
-    ptr = rows(dix.row_ptr, W + 1)
-    idx = _lower_bound(dix.ent_ts, ptr[:, :-1], ptr[:, 1:], ts[:, None],
-                       nsteps)
-    # window-local parent ids: a no-link (-1) stays negative, and the
-    # parent of a node inside the stratum lies inside it
-    parent = (dix.ent_parent[jnp.clip(idx, 0, dix.ent_ts.shape[0] - 1)]
-              - start[:, None])
+    def position(x):
+        loc = jnp.clip(x - base, 0, W - 1)
+        return jnp.where(x >= 0, jnp.take_along_axis(lane_of, loc, axis=1),
+                         NONE)
+
+    # -- 2. per-(query, node) parent link at ts, as a lane ---------------
+    idx = _lower_bound(dix.ent_ts, dix.row_ptr[ids], dix.row_ptr[ids + 1],
+                       ts[:, None], nsteps)
+    parent = position(dix.ent_parent[jnp.clip(idx, 0,
+                                              dix.ent_ts.shape[0] - 1)])
 
     # -- 3. per-(query, node) activity ----------------------------------
-    active = (
-        inside
-        & (rows(dix.live_from) <= ts[:, None])
-        & (ts[:, None] <= rows(dix.live_to))
-        & (rows(dix.node_ct) <= te[:, None])
-    )
+    active = live & (dix.node_ct[ids] <= te[:, None])
 
     # -- 4. pointer jumping along active parent links --------------------
-    pc = jnp.clip(parent, 0, W - 1)
+    pc = jnp.clip(parent, 0, M - 1)
     up = (parent >= 0) & active & jnp.take_along_axis(active, pc, axis=1)
-    top0 = jnp.where(up, pc, jnp.arange(W, dtype=jnp.int32)[None, :])
+    top0 = jnp.where(up, pc, lane)
 
     def body(state):
         top, _, rounds = state
@@ -533,14 +608,14 @@ def _component_masks(dix: DeviceIndex, slot, e0_ok, e0c, ts, te):
         lambda s: s[1], body, (top0, jnp.array(True), jnp.int32(0)))
 
     # -- 5. membership: top[x] == top[entry_b], masked by activity -------
-    e0l = jnp.clip(e0c - start, 0, W - 1)
-    root = jnp.take_along_axis(top, e0l[:, None], axis=1)
-    member = (active & (top == root) & e0_ok[:, None]).astype(jnp.int32)
+    e0l = position(e0c[:, None])
+    root = jnp.take_along_axis(top, jnp.clip(e0l, 0, M - 1), axis=1)
+    member = (active & (top == root) & (e0_ok[:, None] & (e0l >= 0))
+              ).astype(jnp.int32)
 
     out = jnp.zeros((B, n), jnp.int32)
-    bi = jnp.broadcast_to(jnp.arange(B, dtype=jnp.int32)[:, None], (B, W))
-    out = out.at[bi, rows(dix.node_u)].max(member)
-    out = out.at[bi, rows(dix.node_v)].max(member)
+    out = out.at[bi, dix.node_u[ids]].max(member)
+    out = out.at[bi, dix.node_v[ids]].max(member)
     return out.astype(bool), rounds
 
 
@@ -565,6 +640,7 @@ def batch_query(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     B = u.shape[0]
     if dix.num_nodes == 0:
         return jnp.zeros((B, dix.n), bool), jnp.int32(0)
+    ts = _first_day(ts)
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
     return _component_masks(dix, u, e0_ok, e0c, ts, te)
 
@@ -582,6 +658,7 @@ def batch_query_full(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     if dix.num_nodes == 0:
         return (jnp.zeros((B, dix.n), bool),
                 jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
+    ts = _first_day(ts)
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[u], dix.vrow_ptr[u + 1], ts, te)
     vmask, rounds = _component_masks(dix, u, e0_ok, e0c, ts, te)
     return vmask, _version_member(dix, vmask, ts, te), rounds
@@ -605,6 +682,7 @@ def batch_query_full_mixed(dix: DeviceIndex, slot: jnp.ndarray,
     if dix.num_nodes == 0:
         return (jnp.zeros((B, dix.n), bool),
                 jnp.zeros((B, dix.ver_src.shape[0]), bool), jnp.int32(0))
+    ts = _first_day(ts)
     e0_ok, e0c = _entry_nodes(dix, dix.vrow_ptr[slot],
                               dix.vrow_ptr[slot + 1], ts, te)
     vmask, rounds = _component_masks(dix, slot, e0_ok, e0c, ts, te)
@@ -670,6 +748,7 @@ def window_sweep(dix: DeviceIndex, u: jnp.ndarray, ts: jnp.ndarray,
     W = ts.shape[0]
     if dix.num_nodes == 0:
         return jnp.zeros((W, dix.n), bool), jnp.int32(0)
+    ts = _first_day(ts)
     vlo = jnp.broadcast_to(dix.vrow_ptr[u], (W,))
     vhi = jnp.broadcast_to(dix.vrow_ptr[u + 1], (W,))
     e0_ok, e0c = _entry_nodes(dix, vlo, vhi, ts, te)

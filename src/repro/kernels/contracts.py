@@ -133,6 +133,8 @@ LAYOUT_CONTRACTS: dict[str, tuple[str, int]] = {
     "ver_src": ("int32", 1),
     "ver_k": ("int32", 1),
     "knode_ptr": ("int32", 1),
+    "live_ptr": ("int32", 1),
+    "live_node": ("int32", 1),
 }
 
 

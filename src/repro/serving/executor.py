@@ -79,11 +79,13 @@ class ShardedExecutor:
     Every launch runs as three live spans on the calling thread:
     ``executor.dispatch`` (pad, upload, enqueue, any compile),
     ``executor.wait`` (until the outputs are ready; carries the launch's
-    ``jump_rounds`` and ``jump_width``) and ``executor.download`` (the
-    masks and the round count in one ``device_get``). The rounds add to
-    the ``jump_rounds`` counter, the mirror's static node window
-    (``DeviceIndex.max_stratum_nodes``) to ``jump_width`` and the launch
-    to ``jump_launches``, traced or not.
+    ``jump_rounds``, ``jump_width`` and ``live_width``) and
+    ``executor.download`` (the masks and the round count in one
+    ``device_get``). The rounds add to the ``jump_rounds`` counter, the
+    mirror's widest stratum (``DeviceIndex.max_stratum_nodes``, which the
+    live lists are drawn from) to ``jump_width``, its longest live list
+    (``DeviceIndex.max_live_nodes``, the width each row propagates over)
+    to ``live_width`` and the launch to ``jump_launches``, traced or not.
     """
 
     def __init__(self, devices=None, *, metrics=None, tracer=None):
@@ -135,7 +137,7 @@ class ShardedExecutor:
     def _collect(self, out, dix: DeviceIndex) -> list:
         """Wait for a launch's outputs (masks..., rounds), then download
         them in one ``device_get``; returns the host masks and counts the
-        launch's pointer-jump rounds and width."""
+        launch's pointer-jump rounds and widths."""
         with self.tracer.span("executor.wait") as wait:
             # repro: ignore[hot-path-transfer] — block_until_ready, the sync
             jax.block_until_ready(out)
@@ -144,12 +146,14 @@ class ShardedExecutor:
             # repro: ignore[hot-path-transfer] — device_get of masks + rounds
             *masks, rounds = jax.device_get(out)
         rounds = int(rounds)
-        width = dix.max_stratum_nodes
+        width, live = dix.max_stratum_nodes, dix.max_live_nodes
         wait.set("jump_rounds", rounds)
         wait.set("jump_width", width)
+        wait.set("live_width", live)
         if self.metrics is not None:
             self.metrics.count("jump_rounds", rounds)
             self.metrics.count("jump_width", width)
+            self.metrics.count("live_width", live)
             self.metrics.count("jump_launches")
         return [np.asarray(m) for m in masks]
 

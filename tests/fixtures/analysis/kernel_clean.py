@@ -64,4 +64,5 @@ def tiny_layout(n_entries):
         "vrow_ptr": z, "vent_ts": z, "vent_node": z,
         "ver_ts_from": z, "ver_ts_to": z, "ver_ct": z,
         "ver_src": z, "ver_k": z, "knode_ptr": z,
+        "live_ptr": z, "live_node": z,
     }

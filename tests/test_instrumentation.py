@@ -193,6 +193,11 @@ def test_jump_counters_add_up_across_launches():
     assert [s.attrs["jump_width"] for s in waits] == [widest] * 3 + [
         sweep_dix.num_nodes]
     assert metrics.counter("jump_width") == 3 * widest + sweep_dix.num_nodes
+    # and its longest live list, the width each row propagates over
+    lives = [dix.max_live_nodes] * 3 + [sweep_dix.max_live_nodes]
+    assert lives[0] < widest and lives[3] < sweep_dix.num_nodes
+    assert [s.attrs["live_width"] for s in waits] == lives
+    assert metrics.counter("live_width") == sum(lives)
     # three live spans per launch, in order, on this thread
     names = [s.name for s in tracer.spans() if s.name.startswith("executor.")]
     assert names == ["executor.dispatch", "executor.wait",

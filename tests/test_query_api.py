@@ -140,18 +140,60 @@ class TestDeviceModes:
 
     def test_per_k_mirror_window_is_its_whole_forest(self, stack):
         """The control of the stratum window: a per-k mirror is one
-        stratum, so every launch propagates over all of its nodes."""
+        stratum, so its node-to-lane buffer spans all of its nodes, while
+        each row propagates over its live list alone."""
         import jax
         import jax.numpy as jnp
         from test_stratified import _jaxpr_shapes
         g, k, pecb, *_ = stack
         dix = to_device(pecb)
         N = pecb.num_nodes
+        M = dix.max_live_nodes
         assert dix.max_stratum_nodes == dix.num_nodes == N
         assert np.asarray(dix.knode_ptr).tolist() == [0, N]
+        assert M < g.n < N
         q = jnp.zeros((8,), jnp.int32)
         shapes = _jaxpr_shapes(jax.make_jaxpr(batch_query)(dix, q, q, q).jaxpr)
-        assert (8, N) in shapes
+        assert (8, N) in shapes and (8, M) in shapes
+
+    @pytest.mark.parametrize("program", ["batch_query", "batch_query_full",
+                                         "window_sweep"])
+    def test_per_k_mirror_edge_times_match_algorithm_1(self, stack,
+                                                       program):
+        """A per-k mirror's programs at the edges of time — before the
+        first day, on it, on the last, past it — and on the start time
+        of its longest live list answer as Algorithm 1 (vertices) and the
+        induced-edge oracle (edges)."""
+        import jax.numpy as jnp
+        from repro.core.batch_query import batch_query_full
+        g, k, pecb, *_ = stack
+        dix = to_device(pecb)
+        counts = np.diff(np.asarray(dix.live_ptr))
+        t_m = int(np.argmax(counts))
+        assert counts[t_m] == dix.max_live_nodes
+        assert counts[0] == counts[g.t_max + 1] == 0
+        wins = [(-1, 4), (0, g.t_max), (1, 1), (1, g.t_max),
+                (g.t_max, g.t_max), (g.t_max + 1, g.t_max + 1),
+                (t_m, t_m), (t_m, g.t_max)]
+        ts = jnp.asarray([a for a, _ in wins], jnp.int32)
+        te = jnp.asarray([b for _, b in wins], jnp.int32)
+        for u in range(0, g.n, 5):
+            if program == "window_sweep":
+                out = window_sweep(dix, jnp.int32(u), ts, te)
+            else:
+                fn = batch_query if program == "batch_query" \
+                    else batch_query_full
+                out = fn(dix, jnp.full(len(wins), u, jnp.int32), ts, te)
+            vmask = np.asarray(out[0])
+            for i, (a, b) in enumerate(wins):
+                assert set(np.flatnonzero(vmask[i]).tolist()) == \
+                    pecb._component_vertices(u, a, b) == \
+                    tccs_oracle(g, k, u, a, b), (u, a, b)
+                if program == "batch_query_full":
+                    row = np.asarray(out[1])[i][:dix.num_versions]
+                    got = set(pecb.versions.edge_id[np.flatnonzero(row)]
+                              .tolist())
+                    assert got == tccs_oracle_edges(g, k, u, a, b), (u, a, b)
 
     def test_engine_device_route_edge_modes(self, stack):
         g, k, *_ = stack

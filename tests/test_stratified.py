@@ -17,8 +17,8 @@ import pytest
 import jax
 import jax.numpy as jnp
 
-from repro.core.batch_query import (_ARRAY_FIELDS, batch_query,
-                                    batch_query_full,
+from repro.core.batch_query import (_ARRAY_FIELDS, _host_layout,
+                                    batch_query, batch_query_full,
                                     batch_query_full_mixed, mixed_slots,
                                     stratum_device, to_device, window_sweep)
 from repro.core.core_time import (default_ks, extend_stratified_core_times,
@@ -245,6 +245,171 @@ class TestMixedKDevice:
             assert any(r.provenance.route == "sweep" for r in res)
             for r, (a, b) in zip(res, windows):
                 assert r.vertices == tccs_oracle(g, 2, 1, a, b)
+
+
+# ----------------------------------------------------------------------
+# live lists: each row propagates over its stratum's nodes live at ts
+# ----------------------------------------------------------------------
+
+PROGRAMS = {"batch_query": batch_query, "batch_query_full": batch_query_full,
+            "batch_query_full_mixed": batch_query_full_mixed,
+            "window_sweep": window_sweep}
+
+
+def _live_counts(dix) -> np.ndarray:
+    """int[K, t_max + 2]: the length of each (stratum, start time) list."""
+    return np.diff(np.asarray(dix.live_ptr)).reshape(-1, dix.t_max + 2)
+
+
+def _edge_time_windows(dix, ki, t_max):
+    """Windows at the edges of time (before the first day, the first and
+    the last day, past the last), the start time whose list of stratum
+    ki is the mirror's longest, and one whose list is empty."""
+    c = _live_counts(dix)
+    out = [(-2, 3), (0, 2), (0, t_max), (1, t_max), (1, 1),
+           (t_max, t_max), (t_max + 1, t_max + 1), (t_max + 1, t_max + 4)]
+    for t in np.flatnonzero(c[ki] == dix.max_live_nodes)[:1]:
+        out += [(int(t), t_max), (int(t), int(t))]
+    for t in np.flatnonzero(c[ki, 1:t_max + 1] == 0)[:1]:
+        out.append((int(t) + 1, t_max))
+    return out
+
+
+def _run(program, dix, slots, ts, te, k, bucket=32):
+    """(vertex masks, version masks or None) of one padded launch."""
+    b = len(ts)
+    if program == "window_sweep":
+        out = window_sweep(dix, jnp.int32(slots[0]),
+                           jnp.asarray(ts, jnp.int32),
+                           jnp.asarray(te, jnp.int32))
+        return np.asarray(out[0]), None
+    slot, tsp, tep = pad_queries(np.asarray(slots, np.int32),
+                                 np.asarray(ts, np.int32),
+                                 np.asarray(te, np.int32), bucket)
+    if program == "batch_query_full_mixed":
+        out = batch_query_full_mixed(dix, slot, tsp, tep,
+                                     np.full(bucket, k, np.int32))
+    else:
+        out = PROGRAMS[program](dix, slot, tsp, tep)
+    vmask = np.asarray(out[0])
+    assert not vmask[b:].any()              # pad lanes stay empty
+    if program == "batch_query":
+        return vmask[:b], None
+    vermask = np.asarray(out[1])
+    assert not vermask[b:].any()
+    return vmask[:b], vermask[:b]
+
+
+class TestLiveLists:
+    @pytest.mark.parametrize("gi", [0, 1])
+    @pytest.mark.parametrize("mirror", ["fused", "per_k"])
+    def test_layout_matches_brute_force(self, gi, mirror):
+        # row ki*(t_max+2)+t lists stratum ki's nodes with
+        # live_from <= t <= live_to, ascending; M is the longest row
+        g = graphs()[gi]
+        sx = build_stratified_index(g)
+        indexes = ([sx] if mirror == "fused"
+                   else [sx.slice_k(k) for k in sx.supported_ks])
+        T2 = g.t_max + 2
+        for index in indexes:
+            meta, a = _host_layout(index)
+            kp, ptr = a["knode_ptr"], a["live_ptr"]
+            lf, lt = a["live_from"], a["live_to"]
+            assert ptr.shape == ((len(kp) - 1) * T2 + 1,)
+            assert ptr[-1] == np.maximum(lt - lf + 1, 0).sum()
+            longest = 0
+            for ki in range(len(kp) - 1):
+                x = np.arange(kp[ki], kp[ki + 1])
+                for t in range(T2):
+                    r = ki * T2 + t
+                    want = x[(lf[x] <= t) & (t <= lt[x])]
+                    assert np.array_equal(
+                        a["live_node"][ptr[r]:ptr[r + 1]], want), (ki, t)
+                    longest = max(longest, want.size)
+                # the two end rows are empty
+                assert ptr[ki * T2 + 1] == ptr[ki * T2]
+                assert ptr[(ki + 1) * T2] == ptr[(ki + 1) * T2 - 1]
+            assert meta["max_live_nodes"] == max(longest, 1)
+            # a spanning forest of n vertices: fewer than n live nodes
+            assert longest < g.n and longest <= meta["max_stratum_nodes"]
+
+    @pytest.mark.parametrize("mirror", ["fused", "per_k", "stratum"])
+    @pytest.mark.parametrize("program", list(PROGRAMS))
+    def test_programs_match_algorithm_1_at_the_edges(self, program, mirror):
+        # every program on every kind of mirror answers as Algorithm 1
+        # before the first day, on it, on the last, past it, on the start
+        # time of the longest list and on one whose list is empty
+        g = graphs()[0]
+        sx = build_stratified_index(g)
+        fused = to_device(sx)
+        store = sx.versions
+        empty_seen = longest_seen = False
+        for k in sx.supported_ks:
+            ki = sx.k_index(k)
+            if mirror == "fused":
+                dix, base, row_ki = fused, ki * g.n, ki
+            else:
+                dix = (to_device(sx.slice_k(k)) if mirror == "per_k"
+                       else stratum_device(fused, sx, k))
+                base, row_ki = 0, 0
+                store = sx.slice_k(k).versions
+            windows = _edge_time_windows(dix, row_ki, g.t_max)
+            c = _live_counts(dix)[row_ki]
+            empty_seen |= any(1 <= a <= g.t_max and c[a] == 0
+                              for a, _ in windows)
+            longest_seen |= any(0 <= a < c.size
+                                and c[a] == dix.max_live_nodes
+                                for a, _ in windows)
+            for u in (0, 3, 7):
+                ts = [a for a, _ in windows]
+                te = [b for _, b in windows]
+                vmask, vermask = _run(program, dix,
+                                      [base + u] * len(ts), ts, te, k)
+                for i, (a, b) in enumerate(windows):
+                    want = sx.slice_k(k)._component_vertices(u, a, b)
+                    assert set(np.flatnonzero(vmask[i]).tolist()) == want, \
+                        (k, u, a, b)
+                    if vermask is None or (mirror == "fused"
+                                           and program == "batch_query_full"):
+                        continue   # no k filter over the fused versions
+                    got = set(store.edge_id[np.flatnonzero(
+                        vermask[i][:store.num_versions])].tolist())
+                    assert got == tccs_oracle_edges(g, k, u, a, b), \
+                        (k, u, a, b)
+        assert empty_seen and longest_seen
+
+    @pytest.mark.parametrize("mirror", ["fused", "per_k"])
+    @pytest.mark.parametrize("program", list(PROGRAMS))
+    def test_while_body_gathers_live_lists(self, program, mirror):
+        # the pointer-jump rounds gather from (B, M), never from (B, W)
+        g = graphs()[1]
+        sx = build_stratified_index(g)
+        dix = to_device(sx if mirror == "fused" else sx.slice_k(2))
+        B, M, W = 16, dix.max_live_nodes, dix.max_stratum_nodes
+        assert M < W
+        q = jnp.ones((B,), jnp.int32)
+        args = (dix, q, q, q, q) if program == "batch_query_full_mixed" \
+            else (dix, q, q, q)
+        jaxpr = jax.make_jaxpr(PROGRAMS[program])(*args).jaxpr
+        loops = _eqns(jaxpr, "while")
+        assert len(loops) == 1
+        gathers = _eqns(loops[0].params["body_jaxpr"].jaxpr, "gather")
+        assert gathers
+        assert {tuple(e.invars[0].aval.shape) for e in gathers} == {(B, M)}
+
+
+def _eqns(jaxpr, primitive) -> list:
+    """Every equation of ``primitive`` in a jaxpr and its sub-jaxprs."""
+    out = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == primitive:
+            out.append(eqn)
+        for p in eqn.params.values():
+            for sub in (p if isinstance(p, (list, tuple)) else (p,)):
+                inner = getattr(sub, "jaxpr", sub)
+                if hasattr(inner, "eqns"):
+                    out += _eqns(inner, primitive)
+    return out
 
 
 # ----------------------------------------------------------------------
